@@ -116,6 +116,9 @@ def main(emit=print, check: bool = False):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # the child counts on forced host devices: keep it off any accelerator,
+    # which another process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     code = _CHILD % {"repo": repo, "repo_tail": os.path.basename(repo)}
     r = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,

@@ -17,8 +17,9 @@ built step (benchmarks.jaxpr_cost):
   * ``bytes_fused`` / ``dp_int_bytes`` / ``flops`` — the jaxpr_cost
     structural totals (post-fusion HBM-byte estimate, integer dp collective
     bytes, FLOPs);
-  * ``step_ms`` — measured wall-clock per compressed step (CPU interpret
-    mode; relative across rows only, the TARGET is TPU Mosaic).
+  * ``cpu_interpret_step_ms`` — wall-clock per compressed step on the
+    forced CPU host devices, kernels in Pallas interpret mode. It is not a
+    device time and says nothing about the TPU.
 
 ``--check`` asserts the headline HBM-pass property: the fused AdamW route
 performs NO MORE integer-image HBM round-trips than fused SGD — i.e. zero
@@ -111,7 +112,7 @@ def measure(opt_name, comp_name, wire_name):
         "bytes_fused": s["bytes_fused"],
         "dp_int_bytes": s["dp_int_bytes"],
         "flops": s["flops"],
-        "step_ms": (time.time() - t0) / reps * 1e3,
+        "cpu_interpret_step_ms": (time.time() - t0) / reps * 1e3,
     }
 
 rows = {}
@@ -128,6 +129,9 @@ def main(emit=print, check: bool = False):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # the child counts on forced host devices: keep it off any accelerator,
+    # which another process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", _CHILD % {"repo": repo}],
         capture_output=True, text=True, timeout=1800, env=env, cwd=repo,
@@ -157,7 +161,7 @@ def main(emit=print, check: bool = False):
 
     for name, row in rows.items():
         emit(
-            f"fused_family/{name},{row['step_ms']:.1f},"
+            f"fused_family/{name},{row['cpu_interpret_step_ms']:.1f},"
             f"pallas={row['n_pallas_calls']}"
             f";image_rt={row['image_hbm_roundtrips']}"
             f";bytes_fused={row['bytes_fused']:.3e}"

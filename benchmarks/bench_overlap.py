@@ -141,6 +141,9 @@ def main(emit=print, check: bool = False):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # the child counts on forced host devices: keep it off any accelerator,
+    # which another process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", _CHILD % {"repo": repo}],
         capture_output=True, text=True, timeout=1800, env=env, cwd=repo,

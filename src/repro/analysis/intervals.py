@@ -248,6 +248,8 @@ _TRANSFER: Dict[str, Callable] = {
     "gather": lambda ins, e: ins[0],
     "expand_dims": _passthrough,
     "copy": _passthrough,
+    "reshard": _passthrough,
+    "pvary": _passthrough,
     "stop_gradient": _passthrough,
     "optimization_barrier": None,  # multi-out passthrough, handled below
     "concatenate": lambda ins, e: _union_all(ins),
@@ -312,7 +314,7 @@ class _Eval:
 
     def _collective(self, eqn, ins) -> List[Interval]:
         name = eqn.primitive.name
-        if name in ("psum", "psum_scatter", "reduce_scatter"):
+        if name in ("psum", "psum_invariant", "psum_scatter", "reduce_scatter"):
             n = self._axis_prod(eqn)
             if n is None:
                 return [TOP for _ in eqn.outvars]

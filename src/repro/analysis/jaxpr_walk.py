@@ -54,6 +54,8 @@ __all__ = [
 # every structural pass, so additions belong HERE, not in the consumers.
 COLLECTIVES = {
     "psum": "all-reduce",
+    # what lax.psum binds inside a check_vma=True shard_map
+    "psum_invariant": "all-reduce",
     "pmean": "all-reduce",  # only present on JAX builds with a pmean prim
     "all_gather": "all-gather",
     "reduce_scatter": "reduce-scatter",
@@ -68,11 +70,11 @@ COLLECTIVES = {
 # concatenated) — the surface the floatless-wire rule audits. A ppermute hop
 # is included: on the ring route it carries in-flight partial SUMS.
 REDUCING_COLLECTIVES = frozenset(
-    {"psum", "pmean", "pmax", "pmin", "reduce_scatter", "psum_scatter",
-     "ppermute"}
+    {"psum", "psum_invariant", "pmean", "pmax", "pmin", "reduce_scatter",
+     "psum_scatter", "ppermute"}
 )
 
-CALL_PRIMS = ("pjit", "closed_call", "core_call", "custom_jvp_call",
+CALL_PRIMS = ("jit", "closed_call", "core_call", "custom_jvp_call",
               "custom_vjp_call", "custom_vjp_call_jaxpr", "remat", "remat2",
               "checkpoint", "custom_lin")
 
@@ -180,7 +182,7 @@ class DataflowGraph:
 
     Links are value-equality edges, so reachability may traverse them in
     either direction — that is what lets one backward or forward sweep cross
-    shard_map / pjit / scan bodies without modeling each call convention."""
+    shard_map / jit / scan bodies without modeling each call convention."""
 
     defs: Dict[int, object]
     uses: Dict[int, List[object]]
@@ -202,7 +204,7 @@ def build_graph(closed_jaxpr, *, shared_bodies: str = "link") -> DataflowGraph:
 
     ``shared_bodies`` decides what to do with a sub-jaxpr OBJECT that is
     shared by several call sites (jax caches jaxprs, so e.g. one tiny
-    ``pjit(clip)`` body serves every microbatch's call):
+    ``jit[name=clip]`` body serves every microbatch's call):
 
       * ``"link"`` (default): link body vars to EVERY call site. Boundary
         links become a hub joining all call sites, so reachability is merged

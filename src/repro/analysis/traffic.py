@@ -80,7 +80,8 @@ RULES = {
 # carry the wire (metrics / MoE shuffles) and are excluded so they can't
 # pollute the byte account.
 WIRE_COLLECTIVE_PRIMS = frozenset(
-    {"psum", "ppermute", "all_gather", "reduce_scatter", "psum_scatter"}
+    {"psum", "psum_invariant", "ppermute", "all_gather", "reduce_scatter",
+     "psum_scatter"}
 )
 
 

@@ -247,7 +247,8 @@ WIRE_PATH_PRIMS = frozenset({
     "neg", "max", "min", "clamp", "abs", "sign", "floor", "round", "rem",
     "and", "or", "xor", "not", "shift_left", "shift_right_logical",
     "shift_right_arithmetic", "select_n", "stop_gradient",
-    "optimization_barrier", "copy", "ppermute", "all_gather", "psum",
+    "optimization_barrier", "copy", "reshard", "pvary", "ppermute",
+    "all_gather", "psum", "psum_invariant",
 })
 
 

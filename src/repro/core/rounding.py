@@ -19,6 +19,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.prng import flat_index, seed_from_key, uniform_from_counter
+
 # The §5.1 range/limit primitives are owned by the wire subsystem (they
 # define what the transport can carry); re-exported here for the scalar-lane
 # reference path and back-compat. repro.wire deliberately has no module-level
@@ -31,11 +33,18 @@ from repro.wire.base import (  # noqa: F401  (re-exports)
 
 
 def stochastic_round(x: jax.Array, key: jax.Array) -> jax.Array:
-    """Randomized rounding to the nearest integers, unbiased (float dtype out)."""
+    """Randomized rounding to the nearest integers, unbiased (float dtype out).
+
+    The uniforms come from the counter-based PRNG of the Pallas encode kernel
+    (:mod:`repro.kernels.prng`, counter = flat element index, seed drawn from
+    `key`), so this route and the kernel round identically for one key. It
+    is also a few integer ops per element: the TPU compiler spent minutes on
+    ``jax.random.uniform``'s threefry over a gradient of a billion-parameter
+    model, and seconds on this."""
     x = x.astype(jnp.float32)
     lo = jnp.floor(x)
     p = x - lo
-    u = jax.random.uniform(key, x.shape, dtype=jnp.float32)
+    u = uniform_from_counter(flat_index(x.shape), seed_from_key(key))
     return lo + (u < p).astype(jnp.float32)
 
 

@@ -38,7 +38,8 @@ the shift state advanced):
 why they are passed rather than recomputed in-kernel)
 
 (see optim.base.FUSED_SCALAR_TAIL — optim owns the tail order, this module
-owns the arithmetic.)
+owns the arithmetic.) The vector lives in SMEM; the blocks of the other
+operands are chosen from a VMEM budget by ``repro.kernels.ops._block_for``.
 """
 from __future__ import annotations
 
@@ -47,9 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-DEFAULT_BLOCK = (256, 1024)
-
+from jax.experimental.pallas import tpu as pltpu
 
 # ---------------------------------------------------------------------------
 # update arithmetic shared by the dense and packed kernels (operates on the
@@ -181,7 +180,7 @@ def fused_apply_2d(
     shift: jax.Array | None = None,  # (rows, cols) f32 global shift
     *,
     kernel: str = "sgd",
-    block=DEFAULT_BLOCK,
+    block,
     interpret: bool = False,
 ):
     """Dense fused route: (p', opt', shift'|None) in one HBM pass."""
@@ -202,7 +201,7 @@ def fused_apply_2d(
     outs = pl.pallas_call(
         functools.partial(body, has_shift=has_shift),
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [spec] * (len(inputs) - 1),
         out_specs=tuple([spec] * len(out_shape)),
         out_shape=tuple(out_shape),
@@ -226,7 +225,7 @@ def fused_unpack_apply_2d(
     kernel: str = "sgd",
     bits: int = 8,
     nlim: int = 0,  # accumulated bias n_summed * clip_limit
-    block=DEFAULT_BLOCK,
+    block,
     interpret: bool = False,
 ):
     """Packed fused route: unpack in-register + update, one HBM pass."""
@@ -243,7 +242,7 @@ def fused_unpack_apply_2d(
     ispec = pl.BlockSpec((k, bm, bn), lambda i, j: (0, i, j))
     has_shift = shift is not None
     inputs = [scalars.astype(jnp.float32), words, param, *opt]
-    in_specs = [pl.BlockSpec(memory_space=pl.ANY), wspec]
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), wspec]
     in_specs += [ispec] * (1 + len(opt))
     out_shape = [jax.ShapeDtypeStruct(param.shape, param.dtype)]
     out_shape += [jax.ShapeDtypeStruct(o.shape, o.dtype) for o in opt]
@@ -262,77 +261,3 @@ def fused_unpack_apply_2d(
     )(*inputs)
     new_p, new_opt = outs[0], tuple(outs[1 : 1 + n_state])
     return new_p, new_opt, (outs[-1] if has_shift else None)
-
-
-# ---------------------------------------------------------------------------
-# named per-kernel entry points
-# ---------------------------------------------------------------------------
-def fused_adamw_2d(int_sum, param, mu, nu, scalars, shift=None, *,
-                   block=DEFAULT_BLOCK, interpret=False):
-    """Dense decode → bias-corrected moment update → AdamW step, one pass."""
-    p, (m, v), h = fused_apply_2d(
-        int_sum, param, (mu, nu), scalars, shift,
-        kernel="adamw", block=block, interpret=interpret,
-    )
-    return p, m, v, h
-
-
-def fused_unpack_adamw_2d(words, param, mu, nu, scalars, shift=None, *,
-                          bits, nlim, block=DEFAULT_BLOCK, interpret=False):
-    """PackedInt decode → bias-corrected moment update → AdamW step: packed
-    words unpacked in-register, moments never leave registers between decode
-    and apply."""
-    p, (m, v), h = fused_unpack_apply_2d(
-        words, param, (mu, nu), scalars, shift,
-        kernel="adamw", bits=bits, nlim=nlim, block=block,
-        interpret=interpret,
-    )
-    return p, m, v, h
-
-
-# ---------------------------------------------------------------------------
-# legacy single-kernel entry points (SGD, no shift) — kept for the oracle
-# tests and micro-benchmarks; scalar layout [inv_nalpha, lr, mu, wd]
-# ---------------------------------------------------------------------------
-def _legacy_scalars(scalars):
-    """[inv_nalpha, lr, mu, wd] -> [inv_nalpha, clip=1, lr, mu, wd]."""
-    s = scalars.astype(jnp.float32)
-    return jnp.stack([s[0], jnp.float32(1.0), s[1], s[2], s[3]])
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bits", "nlim", "block", "interpret")
-)
-def fused_unpack_update_2d(
-    words: jax.Array,
-    param: jax.Array,
-    mom: jax.Array,
-    scalars: jax.Array,  # [inv_nalpha, lr, mu, wd] f32
-    *,
-    bits: int,
-    nlim: int,
-    block=DEFAULT_BLOCK,
-    interpret: bool = False,
-):
-    p, (m,), _ = fused_unpack_apply_2d(
-        words, param, (mom,), _legacy_scalars(scalars), None,
-        kernel="sgd", bits=bits, nlim=nlim, block=block, interpret=interpret,
-    )
-    return p, m
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def fused_update_2d(
-    int_sum: jax.Array,
-    param: jax.Array,
-    mom: jax.Array,
-    scalars: jax.Array,  # [inv_nalpha, lr, mu, wd] f32
-    *,
-    block=DEFAULT_BLOCK,
-    interpret: bool = False,
-):
-    p, (m,), _ = fused_apply_2d(
-        int_sum, param, (mom,), _legacy_scalars(scalars), None,
-        kernel="sgd", block=block, interpret=interpret,
-    )
-    return p, m

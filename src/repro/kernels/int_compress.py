@@ -4,10 +4,10 @@ One HBM read of the f32 gradient tile, one HBM write of the int32 image —
 the entire Int(α∘g) operator of the paper in a single VMEM pass.
 
 Tiling: 2-D grid over a (rows, cols) view; blocks are (BM, BN) with BN a
-multiple of 128 (lane width) and BM a multiple of 8 (sublane, f32). VMEM
-footprint per step: BM*BN*4B (in) + BM*BN*4B (out) = 2 MiB at the default
-(256, 1024), comfortably inside the ~16 MiB VMEM budget while long enough to
-amortize HBM latency.
+multiple of 128 (lane width) and BM a multiple of 8 (sublane, f32), chosen
+from a VMEM budget by ``repro.kernels.ops._block_for``. The α and seed
+scalars live in SMEM (a vector load from an unplaced memory space is
+illegal on the TPU).
 """
 from __future__ import annotations
 
@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.prng import uniform_from_counter
 
@@ -33,9 +34,6 @@ def clip_limit(bits: int, n_workers: int) -> int:
             "(clip limit degenerates to 0; widen the wire)"
         )
     return lim
-
-
-DEFAULT_BLOCK = (256, 1024)
 
 
 def _kernel(alpha_ref, seed_ref, x_ref, o_ref, *, lim, stochastic, ncols, block):
@@ -71,7 +69,7 @@ def int_compress_2d(
     n_workers: int,
     bits: int = 32,
     stochastic: bool = True,
-    block=DEFAULT_BLOCK,
+    block,
     interpret: bool = False,
 ) -> jax.Array:
     """x: (rows, cols) f32, rows % block[0] == 0, cols % block[1] == 0."""
@@ -86,8 +84,8 @@ def int_compress_2d(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # alpha (scalar, whole array)
-            pl.BlockSpec(memory_space=pl.ANY),  # seed
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # alpha (scalar)
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # seed
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),

@@ -19,17 +19,36 @@ from repro.kernels import block_norms as _bn
 from repro.kernels import fused_update as _fu
 from repro.kernels import int_compress as _ic
 from repro.kernels import wire_pack as _wp
+from repro.kernels.prng import seed_from_key
 
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# Every grid step double-buffers each input and output block in VMEM, so a
+# (bm, bn) block costs 2·bm·bn·B bytes, where B is what ONE position of the
+# 2-D view moves over all of the kernel's streams (each of the k planes of
+# an image-view tensor counts). Half of the TPU v5e's 16 MiB default scoped
+# VMEM limit goes to these buffers; the other half stays free for in-kernel
+# temporaries.
+VMEM_BUDGET = 8 * 2**20
 _SMALL = 2**18
+_LANES = 1024
+_MAX_ROWS = 256
 
 
-def _block_for(size: int):
-    return (8, 128) if size < _SMALL else _ic.DEFAULT_BLOCK
+def _block_for(size: int, stream_bytes: int):
+    """Block of a 2-D view of `size` positions whose kernel moves
+    `stream_bytes` bytes per position: (8, 128) for small arrays, else 1024
+    lanes by the most rows (a power of two in [8, 256]) that VMEM_BUDGET
+    holds. The one place any kernel's block is chosen."""
+    if size < _SMALL:
+        return (8, 128)
+    bm = _MAX_ROWS
+    while bm > 8 and 2 * bm * _LANES * stream_bytes > VMEM_BUDGET:
+        bm //= 2
+    return (bm, _LANES)
 
 
 def _to_2d(flat: jax.Array, block):
@@ -38,10 +57,6 @@ def _to_2d(flat: jax.Array, block):
     padded = (flat.size + chunk - 1) // chunk * chunk
     flat = jnp.pad(flat, (0, padded - flat.size))
     return flat.reshape(padded // bn, bn)
-
-
-def seed_from_key(key: jax.Array) -> jax.Array:
-    return jax.random.bits(key, (), jnp.uint32).astype(jnp.int32)
 
 
 @functools.partial(
@@ -61,7 +76,7 @@ def int_compress(
     interpret = _interpret_default() if interpret is None else interpret
     seed = seed_from_key(key)
     shape = x.shape
-    block = _block_for(x.size)
+    block = _block_for(x.size, 4 + 4)
     x2 = _to_2d(x.reshape(-1).astype(jnp.float32), block)
     out = _ic.int_compress_2d(
         x2,
@@ -105,7 +120,7 @@ def pack_words(
     flat = ints.reshape(-1).astype(jnp.int32)
     m = -(-flat.size // k)
     flat = jnp.pad(flat, (0, k * m - flat.size))
-    block = _block_for(m)
+    block = _block_for(m, 4 * k + 4)
     x3 = _image_view(flat, k, m, block)
     w2 = _wp.pack_words_2d(
         x3, bits=bits, lim=lim, block=block, interpret=interpret
@@ -133,7 +148,7 @@ def unpack_words(
         size *= int(s)
     m = words.size
     assert m == -(-size // k), (m, size, k)
-    block = _block_for(m)
+    block = _block_for(m, 4 + 4 * k)
     w2 = _to_2d(words.reshape(-1), block)
     out3 = _wp.unpack_words_2d(
         w2, bits=bits, nlim=nlim, block=block, interpret=interpret
@@ -167,7 +182,9 @@ def fused_unpack_apply(
     shape, d = param.shape, param.size
     m = words.size
     assert m == -(-d // k), (m, d, k)
-    block = _block_for(m)
+    # words in + k f32 planes of param, state and shift, each in and out
+    n_tensors = 1 + len(opt) + (shift is not None)
+    block = _block_for(m, 4 + 2 * 4 * k * n_tensors)
     w2 = _to_2d(words.reshape(-1), block)
 
     def view(t):
@@ -207,7 +224,9 @@ def fused_apply(
     integer dequantization. Returns (param', opt', shift'|None)."""
     interpret = _interpret_default() if interpret is None else interpret
     shape = param.shape
-    block = _block_for(param.size)
+    # integer lanes in + f32 param, state and shift, each in and out
+    n_tensors = 1 + len(opt) + (shift is not None)
+    block = _block_for(param.size, int_sum.dtype.itemsize + 2 * 4 * n_tensors)
     to2 = lambda t: _to_2d(t.reshape(-1).astype(jnp.float32), block)
     po, opt2, ho = _fu.fused_apply_2d(
         _to_2d(int_sum.reshape(-1), block), to2(param),
@@ -283,7 +302,7 @@ def fused_update(
 def sq_norm(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """||x||² via the block-norms reduction kernel (single block)."""
     interpret = _interpret_default() if interpret is None else interpret
-    block = _block_for(x.size)
+    block = _block_for(x.size, 4)
     x2 = _to_2d(x.reshape(-1).astype(jnp.float32), block)
     out = _bn.block_norms_2d(
         x2, block_rows=x2.shape[0], tile=(block[0], x2.shape[1]), interpret=interpret

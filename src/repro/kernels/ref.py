@@ -54,10 +54,11 @@ def pack_words_ref(
     lim = _INT_LIM[bits] // max(n_workers, 1)
     flat = ints.reshape(-1).astype(jnp.int32)
     m = -(-flat.size // k)
-    chunks = jnp.pad(flat, (0, k * m - flat.size)).reshape(k, m)
+    flat = jnp.pad(flat, (0, k * m - flat.size))
     word = jnp.zeros((m,), jnp.uint32)
     for j in range(k):
-        word = word + (chunks[j] + lim).astype(jnp.uint32) * jnp.uint32(
+        chunk = flat[j * m : (j + 1) * m]
+        word = word + (chunk + lim).astype(jnp.uint32) * jnp.uint32(
             2 ** (j * bits)
         )
     return word.astype(jnp.int32)
@@ -81,7 +82,7 @@ def unpack_words_ref(
         - n_summed * lim
         for j in range(k)
     ]
-    return jnp.stack(fields).reshape(-1)[:size].reshape(shape)
+    return jnp.concatenate(fields)[:size].reshape(shape)
 
 
 def fused_unpack_update_ref(

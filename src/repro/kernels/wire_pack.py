@@ -19,9 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK = (256, 1024)
-
-
 def _pack_kernel(x_ref, o_ref, *, k, bits, lim):
     x = x_ref[...]  # (k, bm, bn) int32
     word = x[0] + lim
@@ -45,7 +42,7 @@ def pack_words_2d(
     *,
     bits: int,
     lim: int,
-    block=DEFAULT_BLOCK,
+    block,
     interpret: bool = False,
 ) -> jax.Array:
     k, rows, cols = x.shape
@@ -70,7 +67,7 @@ def unpack_words_2d(
     *,
     bits: int,
     nlim: int,  # accumulated bias n_summed * clip_limit
-    block=DEFAULT_BLOCK,
+    block,
     interpret: bool = False,
 ) -> jax.Array:
     rows, cols = words.shape

@@ -1,8 +1,10 @@
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede every other import (jax locks device count on first init).
-# Only the dry-run sees 512 placeholder devices; tests/benches see 1.
+# Only the dry-run sees 512 placeholder devices, on the CPU backend (never an
+# accelerator another process may hold); tests/benches see 1.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 

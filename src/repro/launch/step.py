@@ -47,7 +47,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -69,7 +68,12 @@ from repro.models.encdec import (
 from repro.models.transformer import lm_forward, lm_logits_local, lm_loss
 from repro.optim import base as optb
 from repro.optim.base import Optimizer
-from repro.optim.zero1 import zero1_init, zero1_state_specs, zero1_update
+from repro.optim.zero1 import (
+    own_row,
+    zero1_init,
+    zero1_state_specs,
+    zero1_update,
+)
 from repro.parallel import collectives as coll
 from repro.utils.tree import tree_abs_max
 from repro.wire import bucketing
@@ -355,23 +359,25 @@ def _fused_plan(base_opt: Optimizer, compressor: Compressor) -> str:
 def _clip_factor(layout: Layout, clip_norm, *, ghat=None, int_sum=None,
                  alphas=None, shift=None):
     """Global-norm gradient clip factor min(1, c/||ĝ||). For the fused
-    integer route ||ĝ||² is computed straight off the wire payload
-    (||ĝ_l||² = ||Σints_l||²/(nα_l)², plus the replicated shift h for the
-    IntDIANA decode ĝ = h + Σints/(nα)) so ĝ is never materialized — the
-    elementwise add fuses into the reduction."""
+    integer route ||ĝ||² is computed straight off the wire payload, decoded
+    in-register exactly as WireFormat.decode does (ĝ = Σints·(1/(nα)), plus
+    the replicated shift h for IntDIANA), so ĝ is never materialized — the
+    elementwise decode fuses into the reduction — and both routes clip by
+    the same factor."""
     if int_sum is not None:
         n = layout.ctx.n
         if shift is None:
             leaf_sq = jax.tree.map(
-                lambda s, a: jnp.sum(jnp.square(s.astype(jnp.float32)))
-                / jnp.square(n * a),
+                lambda s, a: jnp.sum(
+                    jnp.square(s.astype(jnp.float32) * (1.0 / (n * a)))
+                ),
                 int_sum,
                 alphas,
             )
         else:
             leaf_sq = jax.tree.map(
                 lambda s, a, h: jnp.sum(
-                    jnp.square(h + s.astype(jnp.float32) / (n * a))
+                    jnp.square(h + s.astype(jnp.float32) * (1.0 / (n * a)))
                 ),
                 int_sum,
                 alphas,
@@ -827,16 +833,10 @@ def build_init_state(
             opt_local, layout.dp_spec, model_axis=layout.model_axis
         )
 
-        from repro.optim.zero1 import shard_leaf
-
         def init_fn(params):
             dp_index = layout.ctx.worker_index()
-            masters_full = jax.tree.map(
-                lambda p: shard_leaf(p, layout.n_dp), params
-            )
             my = jax.tree.map(
-                lambda m: lax.dynamic_slice_in_dim(m, dp_index, 1, 0),
-                masters_full,
+                lambda p: own_row(p, layout.n_dp, dp_index)[None], params
             )
             base_state = base_opt.init(jax.tree.map(lambda m: m[0], my))
             restack = lambda t: jax.tree.map(

@@ -20,6 +20,7 @@ from repro.checkpoint import CheckpointStore
 from repro.configs import ShapeConfig, get_arch, smoke_config
 from repro.core import make_compressor, with_wire
 from repro.data.synthetic import SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.step import build_init_state, build_train_step
 from repro.models.transformer import init_lm_params
 from repro.optim import adamw, sgd
@@ -151,6 +152,7 @@ def main():
                          "i+1's backward")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)

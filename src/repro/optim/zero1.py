@@ -10,10 +10,13 @@ Storage layout per parameter leaf (LOCAL TP shard flattened and padded):
                                             PartitionSpec (dp_axes, "model")
 
 Step protocol (inside shard_map):
-    1. ĝ (decoded IntSGD aggregate, identical on all dp members) is reshaped
-       to (n_dp, k/n_dp) and each member takes ITS row;
+    1. ĝ (decoded IntSGD aggregate, identical on all dp members) is flattened
+       and padded to n_dp·(k/n_dp), and each member slices out ITS row;
     2. the base optimizer update runs on the f32 shard;
     3. the new bf16 shard is all-gathered over dp → full new params.
+No step ever forms the (n_dp, k/n_dp) view of a full leaf: the TPU compiler
+spends minutes on such a view of a large leaf, and seconds on a 1-D slice
+and a tiled gather.
 The all-gather is bf16 (half the bytes of the f32 gradient it replaces in a
 ZeRO-less design) and is the only extra collective ZeRO-1 introduces.
 """
@@ -37,8 +40,17 @@ def _pad_rows(flat, n_dp):
 
 
 def shard_leaf(x, n_dp):
-    """param leaf -> (n_dp, k/n_dp) f32 master layout."""
+    """param leaf -> (n_dp, k/n_dp) f32 master layout (shapes only: the
+    state builders take one row each with :func:`own_row`)."""
     return _pad_rows(x.reshape(-1).astype(jnp.float32), n_dp)
+
+
+def own_row(x, n_dp, dp_index):
+    """This dp member's (k/n_dp,) f32 row of the master layout of leaf x."""
+    flat = x.reshape(-1).astype(jnp.float32)
+    per = -(-flat.size // n_dp)
+    flat = jnp.pad(flat, (0, per * n_dp - flat.size))
+    return lax.dynamic_slice_in_dim(flat, dp_index * per, per)
 
 
 def zero1_init(base: Optimizer, params, n_dp: int):
@@ -64,24 +76,18 @@ def zero1_update(
     row); ghat is the full local-TP gradient tree."""
     masters = state["master"]
 
-    def own_row(leaf):  # (1, k) local -> (k,); scalars (adam count) pass through
+    def local_row(leaf):  # (1, k) local -> (k,); scalars (adam count) pass through
         return leaf[0] if leaf.ndim >= 2 else leaf
 
-    g_rows = jax.tree.map(
-        lambda g, m: lax.dynamic_slice_in_dim(
-            _pad_rows(g.reshape(-1).astype(jnp.float32), n_dp), dp_index, 1, 0
-        )[0],
-        ghat,
-        masters,
-    )
-    m_rows = jax.tree.map(own_row, masters)
-    b_rows = jax.tree.map(own_row, state["base"])
+    g_rows = jax.tree.map(lambda g: own_row(g, n_dp, dp_index), ghat)
+    m_rows = jax.tree.map(local_row, masters)
+    b_rows = jax.tree.map(local_row, state["base"])
     updates, new_base = base.update(g_rows, b_rows, m_rows, eta)
     new_master = jax.tree.map(lambda m, u: m + u, m_rows, updates)
 
     def gather_param(mrow, p_like):
-        full = coll.all_gather_flat(mrow.astype(param_dtype), dp_axes, n_dp)
-        return full.reshape(-1)[: p_like.size].reshape(p_like.shape)
+        full = coll.all_gather_concat(mrow.astype(param_dtype), dp_axes)
+        return full[: p_like.size].reshape(p_like.shape)
 
     new_params = jax.tree.map(gather_param, new_master, params_like)
     restack = lambda t: jax.tree.map(lambda x: x[None] if x.ndim >= 1 else x, t)

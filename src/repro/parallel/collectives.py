@@ -1,18 +1,13 @@
-"""Version-portable collectives layer — THE distributed execution surface.
+"""Collectives layer — THE distributed execution surface.
 
 Every shard_map entry point, mesh helper and raw collective the codebase
 uses routes through this module, so JAX API drift is absorbed in exactly one
-place. Two generations of the API are supported by feature detection (never
-by version-string comparison):
-
-  * new-style ``jax.shard_map``         (replication check kwarg: check_vma)
-  * ``jax.experimental.shard_map``      (replication check kwarg: check_rep)
+place. It is written for the installed JAX (0.9.0, pinned in
+pyproject.toml): ``jax.shard_map`` with its ``check_vma`` flag.
 
 Contract (relied on by launch/step.py, the tests and future backends):
 
-  * :func:`shard_map` — keyword-only (mesh, in_specs, out_specs, check_vma);
-    ``check_vma`` is translated to whatever the installed JAX calls its
-    replication/varying-manual-axes check.
+  * :func:`shard_map` — keyword-only (mesh, in_specs, out_specs, check_vma).
   * :func:`sharded_jit` — the step-builder pipeline terminal: shard_map the
     body, jit it with NamedShardings derived from the same specs, optionally
     donate buffers. All step builders terminate here.
@@ -23,9 +18,7 @@ Contract (relied on by launch/step.py, the tests and future backends):
 """
 from __future__ import annotations
 
-import inspect
-from functools import partial
-from typing import Any, Callable, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,32 +27,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 # ---------------------------------------------------------------------------
-# shard_map resolution (the ONE place in src/ that touches the raw API)
+# shard_map (the ONE place in src/ that touches the raw API)
 # ---------------------------------------------------------------------------
-def _resolve_shard_map() -> Tuple[Callable, str | None]:
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental import shard_map as _esm
-
-        fn = _esm.shard_map
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-        params = {}
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return fn, name
-    return fn, None
-
-
-_SHARD_MAP, _CHECK_KWARG = _resolve_shard_map()
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Portable shard_map: maps ``check_vma`` onto the installed API."""
-    kwargs = {_CHECK_KWARG: check_vma} if _CHECK_KWARG else {}
-    return _SHARD_MAP(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
     )
 
 
@@ -188,10 +161,26 @@ def psum_wire_words(words, axes):
     The whole tree rides ONE psum primitive — the serial reference the
     bucketed route (:func:`psum_wire_words_bucketed`) is measured against:
     one monolithic collective on the critical path vs many interleavable
-    ones (benchmarks/bench_overlap.py counts exactly this).
+    ones (benchmarks/bench_overlap.py counts exactly this). ``lax.psum`` of
+    a tree binds one psum per leaf, so the leaves are concatenated into one
+    flat payload first (one per word dtype; a codec has one) and split back
+    after the sum, as the bucketed route does.
     """
     _check_wire_dtypes(words)
-    return lax.psum(words, axes)
+    leaves, treedef = jax.tree.flatten(words)
+    out = list(leaves)
+    by_dtype = {}
+    for i, v in enumerate(leaves):
+        by_dtype.setdefault(v.dtype, []).append(i)
+    for idx in by_dtype.values():
+        flat = jnp.concatenate([leaves[i].reshape(-1) for i in idx])
+        summed = lax.psum(flat, axes)
+        off = 0
+        for i in idx:
+            v = leaves[i]
+            out[i] = summed[off: off + v.size].reshape(v.shape)
+            off += v.size
+    return jax.tree.unflatten(treedef, out)
 
 
 def ring_allreduce_int(v, axis: str, n: int):
@@ -302,10 +291,16 @@ def all_gather_flat(v, axes: Sequence[str], n: int):
     return out.reshape((n,) + v.shape)
 
 
-def all_gather_concat(v, axes: Sequence[str], n: int):
+def all_gather_concat(v, axes: Sequence[str]):
     """Gather over `axes` concatenating along the existing leading dim
-    (the ZeRO-1 bf16 param all-gather layout)."""
-    return all_gather_flat(v, axes, n).reshape((-1,) + v.shape[1:])
+    (the ZeRO-1 param all-gather layout), worker order as
+    :func:`linear_axis_index`. Tiled gathers never stack an (n, ...)
+    intermediate, which the TPU compiler takes minutes over for a large
+    leaf."""
+    out = v
+    for ax in reversed(tuple(axes)):
+        out = lax.all_gather(out, ax, tiled=True)
+    return out
 
 
 def ppermute_ring(x, axis: str, n: int, *, shift: int = 1):

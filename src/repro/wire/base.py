@@ -154,8 +154,10 @@ class WireFormat:
     def decode(
         self, ints: jax.Array, alpha: jax.Array, *, n_workers: int
     ) -> jax.Array:
-        """Summed integer image -> gradient estimate (1/(nα)) Σ Int(α g_i)."""
-        return ints.astype(jnp.float32) / (n_workers * alpha)
+        """Summed integer image -> gradient estimate (1/(nα)) Σ Int(α g_i).
+        Multiplies by the reciprocal, as the fused kernels do with their
+        ``inv_nalpha`` scalar, so both update routes decode bit-identically."""
+        return ints.astype(jnp.float32) * (1.0 / (n_workers * alpha))
 
     # ---- transport stages (per-format) ---------------------------------
     def pack(self, ints: jax.Array, *, n_workers: int):

@@ -64,10 +64,12 @@ class PackedInt(WireFormat):
         k, b = self.fields, self.bits
         flat = ints.reshape(-1).astype(jnp.int32)
         m = self.words_len(flat.size)
-        chunks = jnp.pad(flat, (0, k * m - flat.size)).reshape(k, m)
+        flat = jnp.pad(flat, (0, k * m - flat.size))
+        # chunks are static 1-D slices, never a (k, m) array: the TPU
+        # compiler takes minutes over a (k, m) view of a large image
         word = jnp.zeros((m,), jnp.int32)
         for j in range(k):  # k is static; the adds fuse into one pass
-            word = word + ((chunks[j] + lim) << (j * b))
+            word = word + ((flat[j * m : (j + 1) * m] + lim) << (j * b))
         return word
 
     def unpack(
@@ -90,7 +92,7 @@ class PackedInt(WireFormat):
         fields = [
             ((words >> (j * b)) & mask) - n_summed * lim for j in range(k)
         ]
-        flat = jnp.stack(fields).reshape(-1)[:size]
+        flat = jnp.concatenate(fields)[:size]  # chunk-major, as in pack
         return flat.astype(jnp.int32).reshape(shape)
 
     def wire_bytes(self, size: int) -> int:

@@ -17,6 +17,7 @@ def run_forced_mesh(script: str, timeout=420):
     change the forced-mesh environment."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices, never an accelerator
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,

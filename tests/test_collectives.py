@@ -1,5 +1,5 @@
-"""The version-portable collectives layer: shim resolution, the single-
-resolution-point invariant, and axis primitives under vmap simulation."""
+"""The collectives layer: the shard_map shim, the single-resolution-point
+invariant, and axis primitives under vmap simulation."""
 import os
 import re
 
