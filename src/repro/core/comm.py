@@ -20,6 +20,7 @@ from typing import Tuple
 
 import jax
 
+from repro.launch import stages
 from repro.parallel import collectives as coll
 from repro.wire import bucketing
 
@@ -79,6 +80,11 @@ class CommCtx:
         """
         if getattr(wf, "transport", "psum") == "gather":
             return self._gather_wire(ints, wf)
+        return self._psum_wire(ints, wf)
+
+    @stages.scoped("wire")
+    def _psum_wire(self, ints, wf):
+        """The psum-shaped transport (see :meth:`psum_wire`)."""
         words = jax.tree.map(
             lambda v: wf.pack(v, n_workers=self.n), ints
         )
@@ -100,6 +106,7 @@ class CommCtx:
         )
         return words_sum, int_sum
 
+    @stages.scoped("wire")
     def _gather_wire(self, ints, wf):
         """The gather-shaped transport (see :meth:`psum_wire`)."""
         payload = jax.tree.map(

@@ -45,6 +45,7 @@ from repro.parallel import collectives as coll
 
 from repro.core.comm import CommCtx, fold_worker_key
 from repro.core.stats import DxStats, TreeDims, local_tree_dims
+from repro.launch import stages
 from repro.wire import DenseInt, WireFormat, make_wire_format
 from repro.core.scaling import (
     AlphaBlockwise,
@@ -61,6 +62,7 @@ def _leaf_dims(params):
     return jax.tree.map(lambda x: float(x.size), params)
 
 
+@stages.scoped("wire")
 def aggregate_exact(grads, ctx: CommCtx):
     """Full-precision mean over workers (step-0 / no-compression path)."""
     return ctx.pmean(grads)
@@ -244,6 +246,7 @@ class IntSGD(Compressor):
             return {"alpha": alpha, "ef": ef}
         return alpha
 
+    @stages.scoped("alpha")
     def _alphas(self, state, grads, eta, n, dims: TreeDims | None):
         if dims is None:
             dims = local_tree_dims(grads)
@@ -284,19 +287,21 @@ class IntSGD(Compressor):
         alpha_state, ef = self._split_state(state)
         work = grads
         if ef is not None:
-            work = jax.tree.map(
-                lambda g, r: g.astype(jnp.float32) + r, grads, ef
-            )
+            with stages.stage("encode"):
+                work = jax.tree.map(
+                    lambda g, r: g.astype(jnp.float32) + r, grads, ef
+                )
         alphas = self._alphas(alpha_state, work, eta, n, dims)
-        akeys = _leaf_keys(fold_worker_key(key, ctx), work)
-        ints = jax.tree.map(
-            lambda g, a, k: wf.encode(
-                g, a, k, n_workers=n * n_accum, stochastic=self.stochastic
-            ),
-            work,
-            alphas,
-            akeys,
-        )
+        with stages.stage("encode"):
+            akeys = _leaf_keys(fold_worker_key(key, ctx), work)
+            ints = jax.tree.map(
+                lambda g, a, k: wf.encode(
+                    g, a, k, n_workers=n * n_accum, stochastic=self.stochastic
+                ),
+                work,
+                alphas,
+                akeys,
+            )
         return ints, alphas
 
     def aggregate_wire(self, state, grads, *, key, eta, ctx: CommCtx, dims=None):
@@ -311,7 +316,8 @@ class IntSGD(Compressor):
         ints, alphas = self.encode_ints(
             state, grads, key=key, eta=eta, ctx=ctx, dims=dims
         )
-        max_local = coll.pmax(tree_abs_max(ints), ctx.axes)
+        with stages.stage("counters"):
+            max_local = coll.pmax(tree_abs_max(ints), ctx.axes)
         # THE wire: codec-packed integer aggregation. On TPU this is the ICI
         # collective carrying only integer transport planes — the paper's
         # INA/all-reduce analog, at bits/8 bytes per coordinate for the
@@ -323,19 +329,21 @@ class IntSGD(Compressor):
             # worker's work tensor is carried into the next step. local_image
             # re-derives the transmitted selection from the same ints —
             # XLA CSEs it against pack's top_k, so no second selection runs.
-            work = jax.tree.map(
-                lambda g, r: g.astype(jnp.float32) + r, grads, ef
-            )
-            local = jax.tree.map(
-                lambda v: wf.local_image(v, n_workers=n), ints
-            )
-            ef = jax.tree.map(
-                lambda w, l, a: w - l.astype(jnp.float32) / a,
-                work, local, alphas,
-            )
+            with stages.stage("encode"):
+                work = jax.tree.map(
+                    lambda g, r: g.astype(jnp.float32) + r, grads, ef
+                )
+                local = jax.tree.map(
+                    lambda v: wf.local_image(v, n_workers=n), ints
+                )
+                ef = jax.tree.map(
+                    lambda w, l, a: w - l.astype(jnp.float32) / a,
+                    work, local, alphas,
+                )
             state = {"alpha": alpha_state, "ef": ef}
-        max_int = tree_abs_max(int_sum)
-        bits = 1.0 + jnp.ceil(jnp.log2(jnp.maximum(max_int, 1.0) + 1.0))
+        with stages.stage("counters"):
+            max_int = tree_abs_max(int_sum)
+            bits = 1.0 + jnp.ceil(jnp.log2(jnp.maximum(max_int, 1.0) + 1.0))
         payload = _payload_bytes(wf, grads)
         return (
             WireAggregate(words=words_sum, ints=int_sum),
@@ -349,9 +357,10 @@ class IntSGD(Compressor):
             state, grads, key=key, eta=eta, ctx=ctx, dims=dims
         )
         wf = self.wire_format
-        ghat = jax.tree.map(
-            lambda s, a: wf.decode(s, a, n_workers=ctx.n), wa.ints, alphas
-        )
+        with stages.stage("decode"):
+            ghat = jax.tree.map(
+                lambda s, a: wf.decode(s, a, n_workers=ctx.n), wa.ints, alphas
+            )
         return ghat, state, metrics
 
     def finish_pipelined(
@@ -366,11 +375,12 @@ class IntSGD(Compressor):
         unused and the state passes through."""
         del local_int_acc
         wf = self.wire_format
-        ghat = jax.tree.map(
-            lambda s, a: wf.decode(s, a, n_workers=ctx.n * n_accum),
-            int_sum_acc,
-            alphas,
-        )
+        with stages.stage("decode"):
+            ghat = jax.tree.map(
+                lambda s, a: wf.decode(s, a, n_workers=ctx.n * n_accum),
+                int_sum_acc,
+                alphas,
+            )
         return ghat, state
 
 
@@ -789,6 +799,7 @@ class IntDIANA(Compressor):
     def observe_update(self, state, dx_stats: DxStats):
         return dict(state, alpha=self.alpha_rule.update(state["alpha"], dx_stats))
 
+    @stages.scoped("alpha")
     def _alphas(self, state, grads, eta, n, dims: TreeDims | None):
         d = dims.d if dims is not None else tree_size(grads)
         a_scalar = self.alpha_rule.alpha(state["alpha"], eta, n, d)
@@ -812,17 +823,18 @@ class IntDIANA(Compressor):
         n = ctx.n
         wf = self.wire_format
         alphas = self._alphas(state, grads, eta, n, dims)
-        akeys = _leaf_keys(fold_worker_key(key, ctx), grads)
-        ints = jax.tree.map(
-            lambda g, h, a, k: wf.encode(
-                g.astype(jnp.float32) - h, a, k,
-                n_workers=n * n_accum, stochastic=self.stochastic,
-            ),
-            grads,
-            state["h_local"],
-            alphas,
-            akeys,
-        )
+        with stages.stage("encode"):
+            akeys = _leaf_keys(fold_worker_key(key, ctx), grads)
+            ints = jax.tree.map(
+                lambda g, h, a, k: wf.encode(
+                    g.astype(jnp.float32) - h, a, k,
+                    n_workers=n * n_accum, stochastic=self.stochastic,
+                ),
+                grads,
+                state["h_local"],
+                alphas,
+                akeys,
+            )
         return ints, alphas
 
     def aggregate_wire(self, state, grads, *, key, eta, ctx: CommCtx, dims=None):
@@ -834,15 +846,18 @@ class IntDIANA(Compressor):
         ints, alphas = self.encode_ints(
             state, grads, key=key, eta=eta, ctx=ctx, dims=dims
         )
-        max_local = coll.pmax(tree_abs_max(ints), ctx.axes)
+        with stages.stage("counters"):
+            max_local = coll.pmax(tree_abs_max(ints), ctx.axes)
         # local shift: h_i += Q(g_i - h_i) = (1/α) Int(α (g_i - h_i))
-        h_local = jax.tree.map(
-            lambda h, s, a: h + s.astype(jnp.float32) / a,
-            state["h_local"], ints, alphas,
-        )
+        with stages.stage("decode"):
+            h_local = jax.tree.map(
+                lambda h, s, a: h + s.astype(jnp.float32) / a,
+                state["h_local"], ints, alphas,
+            )
         words_sum, int_sum = ctx.psum_wire(ints, wf)
-        max_int = tree_abs_max(int_sum)
-        bits = 1.0 + jnp.ceil(jnp.log2(jnp.maximum(max_int, 1.0) + 1.0))
+        with stages.stage("counters"):
+            max_int = tree_abs_max(int_sum)
+            bits = 1.0 + jnp.ceil(jnp.log2(jnp.maximum(max_int, 1.0) + 1.0))
         return (
             WireAggregate(words=words_sum, ints=int_sum),
             alphas,
@@ -855,10 +870,11 @@ class IntDIANA(Compressor):
             state, grads, key=key, eta=eta, ctx=ctx, dims=dims
         )
         wf = self.wire_format
-        mean_q = jax.tree.map(
-            lambda s, a: wf.decode(s, a, n_workers=ctx.n), wa.ints, alphas
-        )
-        h_global = jax.tree.map(jnp.add, state["h_global"], mean_q)
+        with stages.stage("decode"):
+            mean_q = jax.tree.map(
+                lambda s, a: wf.decode(s, a, n_workers=ctx.n), wa.ints, alphas
+            )
+            h_global = jax.tree.map(jnp.add, state["h_global"], mean_q)
         # ĝ = h + mean Q(g_i - h_i) == the advanced global shift
         return h_global, dict(state, h_global=h_global), metrics
 
@@ -870,16 +886,17 @@ class IntDIANA(Compressor):
         mean_q = (1/(n·M·α)) ΣΣ ints, h_i += (1/(M·α)) Σ_m ints_i^m,
         ĝ = h_global + mean_q (= new h_global)."""
         wf = self.wire_format
-        h_local = jax.tree.map(
-            lambda h, s, a: h + s.astype(jnp.float32) / (n_accum * a),
-            state["h_local"], local_int_acc, alphas,
-        )
-        mean_q = jax.tree.map(
-            lambda s, a: wf.decode(s, a, n_workers=ctx.n * n_accum),
-            int_sum_acc,
-            alphas,
-        )
-        h_global = jax.tree.map(jnp.add, state["h_global"], mean_q)
+        with stages.stage("decode"):
+            h_local = jax.tree.map(
+                lambda h, s, a: h + s.astype(jnp.float32) / (n_accum * a),
+                state["h_local"], local_int_acc, alphas,
+            )
+            mean_q = jax.tree.map(
+                lambda s, a: wf.decode(s, a, n_workers=ctx.n * n_accum),
+                int_sum_acc,
+                alphas,
+            )
+            h_global = jax.tree.map(jnp.add, state["h_global"], mean_q)
         return h_global, dict(state, h_local=h_local, h_global=h_global)
 
     def fused_shift(self, state):

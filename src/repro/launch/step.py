@@ -58,6 +58,7 @@ from repro.core.compressor import (
 )
 from repro.core.stats import DxStats, TreeDims, scale_dx_stats
 from repro.launch import specs as specs_mod
+from repro.launch import stages
 from repro.models.common import Axes
 from repro.models.decode import lm_decode_step, tp_greedy
 from repro.models.encdec import (
@@ -287,6 +288,7 @@ def _sharded(layout: Layout, body, in_specs, out_specs, *, donate=(),
 # ---------------------------------------------------------------------------
 # shared step-body stages
 # ---------------------------------------------------------------------------
+@stages.scoped("fwd_bwd")
 def _forward_backward(layout: Layout, loss_fn, params, batch):
     loss, grads = jax.value_and_grad(
         lambda p: loss_fn(p, batch, layout.axes, layout.cfg, dtype=jnp.bfloat16)
@@ -307,6 +309,7 @@ def _restack_comp(cs, comp_state_like):
     )
 
 
+@stages.scoped("alpha")
 def _observe_dx(layout: Layout, compressor, base_opt, cs, new_params, params):
     """Δx stats -> α rule, rescaled to gradient-equivalent units
     (base_opt.dx_scale — §4.1 momentum correction)."""
@@ -356,6 +359,7 @@ def _fused_plan(base_opt: Optimizer, compressor: Compressor) -> str:
     return base_opt.fused_kernel
 
 
+@stages.scoped("clip")
 def _clip_factor(layout: Layout, clip_norm, *, ghat=None, int_sum=None,
                  alphas=None, shift=None):
     """Global-norm gradient clip factor min(1, c/||ĝ||). For the fused
@@ -450,12 +454,14 @@ def _pipelined_grad_stage(
         )
         # wire-width metric: what each psum actually carried, not the
         # M-fold accumulated sum
-        max_int = jnp.maximum(max_int, tree_abs_max(int_sum_m))
+        with stages.stage("counters"):
+            max_int = jnp.maximum(max_int, tree_abs_max(int_sum_m))
         loss_acc = loss_acc + loss_m
     ghat, cs = compressor.finish_pipelined(
         cs, int_acc, local_acc, alphas, ctx=layout.ctx, n_accum=n_micro
     )
-    bits = 1.0 + jnp.ceil(jnp.log2(jnp.maximum(max_int, 1.0) + 1.0))
+    with stages.stage("counters"):
+        bits = 1.0 + jnp.ceil(jnp.log2(jnp.maximum(max_int, 1.0) + 1.0))
     return ghat, cs, loss_acc / n_micro, (max_int, bits)
 
 
@@ -467,10 +473,12 @@ def _accum_grad_stage(layout: Layout, loss_fn, params, batch, n_micro: int):
     for m in range(n_micro):
         mb = _microbatch(batch, m, n_micro)
         loss_m, grads_m = _forward_backward(layout, loss_fn, params, mb)
-        g32 = jax.tree.map(lambda g: g.astype(jnp.float32), grads_m)
-        g_acc = g32 if g_acc is None else jax.tree.map(jnp.add, g_acc, g32)
+        with stages.stage("fwd_bwd"):
+            g32 = jax.tree.map(lambda g: g.astype(jnp.float32), grads_m)
+            g_acc = g32 if g_acc is None else jax.tree.map(jnp.add, g_acc, g32)
         loss_acc = loss_acc + loss_m
-    grads = jax.tree.map(lambda g: g / n_micro, g_acc)
+    with stages.stage("fwd_bwd"):
+        grads = jax.tree.map(lambda g: g / n_micro, g_acc)
     return loss_acc / n_micro, grads
 
 
@@ -509,7 +517,8 @@ def _make_train_body(
                 layout, loss_fn, compressor, cs, params, batch, akey, eta,
                 microbatches,
             )
-            metrics = (coll.pmax(max_int, m_axes), coll.pmax(bits, m_axes))
+            with stages.stage("counters"):
+                metrics = (coll.pmax(max_int, m_axes), coll.pmax(bits, m_axes))
         else:
             if microbatches > 1:
                 loss, grads = _accum_grad_stage(
@@ -532,10 +541,11 @@ def _make_train_body(
                         cs, grads, key=akey, eta=eta, ctx=layout.ctx,
                         dims=layout.dims,
                     )
-                metrics = (
-                    coll.pmax(m.max_int, m_axes),
-                    coll.pmax(m.bits_per_coord, m_axes),
-                )
+                with stages.stage("counters"):
+                    metrics = (
+                        coll.pmax(m.max_int, m_axes),
+                        coll.pmax(m.bits_per_coord, m_axes),
+                    )
 
         # replicated global shift the fused decode must add (IntDIANA's
         # h_global; None for shift-free compressors)
@@ -548,7 +558,8 @@ def _make_train_body(
                 shift=shift,
             )
             if ghat is not None:
-                ghat = jax.tree.map(lambda g: g * scale, ghat)
+                with stages.stage("clip"):
+                    ghat = jax.tree.map(lambda g: g * scale, ghat)
             else:  # fused: the clip rides the kernels' scalar vector
                 clip_scale = scale
 
@@ -581,6 +592,7 @@ def _make_train_body(
     return step
 
 
+@stages.scoped("update")
 def _fused_update_stage(layout: Layout, params, opt_state, eta,
                         base_opt: Optimizer, *, ghat, wire_agg, alphas, wf,
                         clip_scale, shift):

@@ -6,7 +6,15 @@ dryrun.py):
 
   PYTHONPATH=src python -m repro.launch.train \\
       --arch granite-8b --smoke --steps 50 --compressor intsgd \\
-      --ckpt-dir /tmp/ckpt [--resume] [--data 2 --model 2]
+      --ckpt-dir /tmp/ckpt [--resume] [--data 2 --model 2] \\
+      [--profile-dir /tmp/prof]
+
+The loop names what the host does in profiler spans: ``data`` (the batch
+made and placed), ``step`` (the call), ``loss_read`` (the wait for the
+loss) and ``checkpoint``. With a profile directory it records steps
+start+5 .. start+9 there, the device's operations under the stage names of
+``launch/stages.py`` beside these spans. The log's ``dt`` is the interval
+between successive loss reads.
 """
 from __future__ import annotations
 
@@ -28,6 +36,9 @@ from repro.optim.schedules import constant, warmup_wrap
 from repro.parallel.collectives import mesh_from_counts
 from repro.wire import wire_format_names
 from repro.wire.bucketing import DEFAULT_BUCKET_WORDS
+
+# steps start+5 .. start+9 are profiled: past the compiles and the warmup
+PROFILE_FIRST, PROFILE_STEPS = 5, 5
 
 
 def train_loop(
@@ -51,6 +62,7 @@ def train_loop(
     bucket_words: int = DEFAULT_BUCKET_WORDS,
     microbatches: int = 1,
     opt: str = "sgd",
+    profile_dir: str | None = None,
 ):
     comp = make_compressor(compressor)
     if wire is not None:
@@ -94,23 +106,44 @@ def train_loop(
     batch_sharding = art.in_shardings[5]
 
     losses = []
-    for i in range(start, steps):
-        batch = data.batch(i, 0)  # global batch; sharded by device_put
-        batch = {k: jax.device_put(v, batch_sharding[k]) for k, v in batch.items()}
-        fn = art.jitted["exact"] if i == 0 else art.jitted["compressed"]
-        t0 = time.time()
-        params, opt_state, comp_state, loss, metrics = fn(
-            params, opt_state, comp_state, jnp.int32(i), jax.random.fold_in(key, i), batch
-        )
-        if i % log_every == 0 or i == steps - 1:
-            print(
-                f"[train] step {i:5d} loss {float(loss):.4f} "
-                f"max_int {float(metrics[0]):.0f} bits {float(metrics[1]):.0f} "
-                f"dt {time.time()-t0:.2f}s"
-            )
-        losses.append(float(loss))
-        if ckpt and (i + 1) % ckpt_every == 0:
-            ckpt.save(i + 1, {"params": params, "opt": opt_state, "comp": comp_state})
+    profiled = range(start + PROFILE_FIRST, start + PROFILE_FIRST + PROFILE_STEPS)
+    tracing = False
+    last_read = time.perf_counter()
+    try:
+        for i in range(start, steps):
+            if profile_dir and i == profiled.start:
+                jax.profiler.start_trace(profile_dir)
+                tracing = True
+            with jax.profiler.TraceAnnotation("data"):
+                batch = data.batch(i, 0)  # global batch; sharded by device_put
+                batch = {k: jax.device_put(v, batch_sharding[k])
+                         for k, v in batch.items()}
+            fn = art.jitted["exact"] if i == 0 else art.jitted["compressed"]
+            with jax.profiler.TraceAnnotation("step"):
+                params, opt_state, comp_state, loss, metrics = fn(
+                    params, opt_state, comp_state, jnp.int32(i),
+                    jax.random.fold_in(key, i), batch,
+                )
+            with jax.profiler.TraceAnnotation("loss_read"):
+                losses.append(float(loss))
+            now = time.perf_counter()
+            dt, last_read = now - last_read, now
+            if i % log_every == 0 or i == steps - 1:
+                print(
+                    f"[train] step {i:5d} loss {losses[-1]:.4f} "
+                    f"max_int {float(metrics[0]):.0f} bits {float(metrics[1]):.0f} "
+                    f"dt {dt * 1e3:.1f}ms"
+                )
+            if ckpt and (i + 1) % ckpt_every == 0:
+                with jax.profiler.TraceAnnotation("checkpoint"):
+                    ckpt.save(i + 1, {"params": params, "opt": opt_state,
+                                      "comp": comp_state})
+            if tracing and i == profiled[-1]:
+                jax.profiler.stop_trace()
+                tracing = False
+    finally:
+        if tracing:
+            jax.profiler.stop_trace()
     if ckpt:
         ckpt.wait()
     return params, losses
@@ -150,6 +183,9 @@ def main():
                     help="grad-accum microbatches; with --overlap ring, "
                          "microbatch i's wire reduce runs behind microbatch "
                          "i+1's backward")
+    ap.add_argument("--profile-dir", default=None,
+                    help="record steps start+5 .. start+9 with the JAX "
+                         "profiler into this directory")
     args = ap.parse_args()
 
     enable_compile_cache()
@@ -166,6 +202,7 @@ def main():
         clip_norm=args.clip_norm, wire=args.wire,
         overlap=args.overlap, bucket_words=args.bucket_words,
         microbatches=args.microbatches, opt=args.opt,
+        profile_dir=args.profile_dir,
     )
 
 

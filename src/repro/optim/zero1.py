@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.launch import stages
 from repro.optim.base import Optimizer
 from repro.parallel import collectives as coll
 
@@ -58,6 +59,7 @@ def zero1_init(base: Optimizer, params, n_dp: int):
     return {"master": masters, "base": base.init(masters)}
 
 
+@stages.scoped("update")
 def zero1_update(
     base: Optimizer,
     state,
