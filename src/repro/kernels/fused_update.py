@@ -16,7 +16,14 @@ and apply, vs 13 tensor touches unfused).
 bit-packed int32 transport words straight off the all-reduce (d/k words
 instead of d integer lanes read from HBM), unpacking k bias-shifted fields
 per word in-register before the identical update arithmetic — so the packed
-route never materializes the integer image at all.
+route never materializes the integer image at all. They take the words as
+(rows, cols) and every f32 tensor as a (k, rows, cols) image view whose
+plane j holds field j. ``repro.kernels.ops.fused_unpack_apply`` builds that
+view from the leaf's own rows where its shape allows (rows of the leaf's
+(rows, C) view a multiple of 8k, and a block that fits): chunk j of the
+canonical word layout is then rows [j·rows/k, (j+1)·rows/k), and the view
+is a reshape that the TPU takes as a bitcast. Other leaves are flattened
+and padded to whole word blocks.
 
 Shift (IntDIANA): with ``has_shift`` every kernel takes one extra f32
 tensor h (the replicated global shift) and emits one extra output. The

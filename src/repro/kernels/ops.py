@@ -4,6 +4,17 @@ Handles arbitrary tensor shapes by flattening to a padded row-major 2-D view
 (pad-at-end keeps the kernel's flat element counter identical to the
 oracle's logical index, so stochastic rounding is bit-exact vs ref.py).
 
+The fused unpack+update route (``fused_unpack_apply``) first tries the
+leaf's own rows instead. The canonical word layout puts field j of word w
+at flat[j·m + w]; for a leaf of shape (..., C) with rows = prod(shape[:-1])
+and rows % k == 0, m = rows/k·C, so chunk j is exactly rows
+[j·rows/k, (j+1)·rows/k) of the leaf's (rows, C) view and
+``t.reshape(k, rows // k, C)`` is the kernel's image view. With rows/k a
+multiple of 8 that reshape only merges and splits major dims at (8, 128)
+tile boundaries: a bitcast on the TPU, with no pad, slice or relayout of
+the f32 tensors either way. Leaves without such a view (1-D leaves, rows
+not a multiple of 8k, or no block that fits) take the padded view.
+
 On non-TPU backends the kernels run under ``interpret=True`` (the kernel body
 executed op-by-op on CPU) — the TARGET remains TPU Mosaic; CPU execution is
 for validation only.
@@ -11,6 +22,7 @@ for validation only.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -42,13 +54,33 @@ def _block_for(size: int, stream_bytes: int):
     """Block of a 2-D view of `size` positions whose kernel moves
     `stream_bytes` bytes per position: (8, 128) for small arrays, else 1024
     lanes by the most rows (a power of two in [8, 256]) that VMEM_BUDGET
-    holds. The one place any kernel's block is chosen."""
+    holds. With ``_native_block`` below, the one place any kernel's block
+    is chosen."""
     if size < _SMALL:
         return (8, 128)
     bm = _MAX_ROWS
     while bm > 8 and 2 * bm * _LANES * stream_bytes > VMEM_BUDGET:
         bm //= 2
     return (bm, _LANES)
+
+
+def _native_block(rows: int, cols: int, stream_bytes: int):
+    """Block of a (rows, cols) view that is not padded to the block grid:
+    bm a multiple of 8 that divides rows, bn a multiple of 128 that divides
+    cols or cols itself, 2·bm·bn·stream_bytes within VMEM_BUDGET; the most
+    positions, then the widest. None where no such block fits."""
+    if rows % 8:
+        return None
+    best = None
+    widths = {cols} | {b for b in range(128, cols, 128) if cols % b == 0}
+    for bn in widths:
+        bm = min(rows, VMEM_BUDGET // (2 * bn * stream_bytes)) // 8 * 8
+        while bm >= 8 and rows % bm:
+            bm -= 8
+        if bm >= 8 and (best is None or (bm * bn, bn) > (best[0] * best[1],
+                                                         best[1])):
+            best = (bm, bn)
+    return best
 
 
 def _to_2d(flat: jax.Array, block):
@@ -157,6 +189,99 @@ def unpack_words(
     return flat.reshape(shape)
 
 
+def _fused_stream_bytes(k: int, n_tensors: int) -> int:
+    """Bytes a word position of the packed fused kernel moves: the word in,
+    and k f32 planes of each of its n_tensors tensors (param, state, shift)
+    in and out."""
+    return 4 + 2 * 4 * k * n_tensors
+
+
+def _native_view(shape, k: int, stream_bytes: int):
+    """(rows // k, C, block) of a leaf's native row-chunk view (module
+    docstring), or None where the leaf takes the padded view."""
+    if len(shape) < 2:
+        return None
+    rows, cols = math.prod(shape[:-1]), shape[-1]
+    if rows % k:
+        return None
+    block = _native_block(rows // k, cols, stream_bytes)
+    return None if block is None else (rows // k, cols, block)
+
+
+def fused_view(shape, *, bits: int, n_tensors: int = 2) -> str:
+    """The view ``fused_unpack_apply`` takes of a leaf of `shape` on a
+    `bits`-bit packed wire, its kernel reading `n_tensors` f32 tensors
+    (param, optimizer state, shift): "native" (the leaf's own rows, no
+    copy) or "padded"."""
+    k = 32 // bits
+    native = _native_view(tuple(shape), k, _fused_stream_bytes(k, n_tensors))
+    return "padded" if native is None else "native"
+
+
+def _native_views(words, shape, k: int, stream_bytes: int):
+    """(words 2-D, view, unview, block) of the native row-chunk view: the
+    words as (rows/k, C), each f32 tensor as (k, rows/k, C)."""
+    rows, cols, block = _native_view(shape, k, stream_bytes)
+
+    def view(t):
+        return t.astype(jnp.float32).reshape(k, rows, cols)
+
+    def unview(t, dt):
+        return t.reshape(shape).astype(dt)
+
+    return words.reshape(rows, cols), view, unview, block
+
+
+def _padded_views(words, shape, k: int, stream_bytes: int):
+    """(words 2-D, view, unview, block) of the padded image view: every
+    tensor flattened, padded to k·m and to whole word blocks, and sliced
+    back after."""
+    m, d = words.size, math.prod(shape)
+    block = _block_for(m, stream_bytes)
+
+    def view(t):
+        flat = t.reshape(-1).astype(jnp.float32)
+        return _image_view(jnp.pad(flat, (0, k * m - d)), k, m, block)
+
+    def unview(t, dt):
+        flat = t.reshape(k, -1)[:, :m].reshape(-1)[:d]
+        return flat.reshape(shape).astype(dt)
+
+    return _to_2d(words.reshape(-1), block), view, unview, block
+
+
+def _leaf_views(words, shape, k: int, stream_bytes: int):
+    """The native row-chunk view where the leaf's shape has one, else the
+    padded image view."""
+    native = _native_view(shape, k, stream_bytes) is not None
+    views = _native_views if native else _padded_views
+    return views(words, shape, k, stream_bytes)
+
+
+def _fused_unpack_in(views, words, param, opt, scalars, shift=None, *,
+                     kernel: str, bits: int, n_summed: int,
+                     interpret: bool | None = None):
+    """The packed fused kernel over the view that `views` (``_leaf_views``,
+    ``_native_views`` or ``_padded_views``) builds."""
+    interpret = _interpret_default() if interpret is None else interpret
+    k = 32 // bits
+    nlim = n_summed * _ic.clip_limit(bits, n_summed)
+    assert words.size == -(-param.size // k), (words.size, param.size, k)
+    stream_bytes = _fused_stream_bytes(k, 1 + len(opt) + (shift is not None))
+    w2, view, unview, block = views(words, param.shape, k, stream_bytes)
+    po3, opt3, ho3 = _fu.fused_unpack_apply_2d(
+        w2, view(param), tuple(view(o) for o in opt), scalars,
+        None if shift is None else view(shift),
+        kernel=kernel, bits=bits, nlim=nlim, block=block,
+        interpret=interpret,
+    )
+    return (
+        unview(po3, param.dtype),
+        tuple(unview(o3, o.dtype) for o3, o in zip(opt3, opt)),
+        None if ho3 is None else unview(ho3, shift.dtype),
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("kernel", "bits", "n_summed", "interpret"),
@@ -175,37 +300,18 @@ def fused_unpack_apply(
 ):
     """PackedInt fused route, any optimizer kernel: the update consumes the
     bit-packed transport words directly (no unpacked integer image ever hits
-    HBM). Returns (param', opt', shift'|None)."""
-    interpret = _interpret_default() if interpret is None else interpret
-    k = 32 // bits
-    nlim = n_summed * _ic.clip_limit(bits, n_summed)
-    shape, d = param.shape, param.size
-    m = words.size
-    assert m == -(-d // k), (m, d, k)
-    # words in + k f32 planes of param, state and shift, each in and out
-    n_tensors = 1 + len(opt) + (shift is not None)
-    block = _block_for(m, 4 + 2 * 4 * k * n_tensors)
-    w2 = _to_2d(words.reshape(-1), block)
+    HBM). Returns (param', opt', shift'|None).
 
-    def view(t):
-        flat = t.reshape(-1).astype(jnp.float32)
-        return _image_view(jnp.pad(flat, (0, k * m - d)), k, m, block)
-
-    po3, opt3, ho3 = _fu.fused_unpack_apply_2d(
-        w2, view(param), tuple(view(o) for o in opt), scalars,
-        None if shift is None else view(shift),
-        kernel=kernel, bits=bits, nlim=nlim, block=block,
-        interpret=interpret,
-    )
-
-    def unview(t, dt):
-        flat = t.reshape(k, -1)[:, :m].reshape(-1)[:d]
-        return flat.reshape(shape).astype(dt)
-
-    return (
-        unview(po3, param.dtype),
-        tuple(unview(o3, o.dtype) for o3, o in zip(opt3, opt)),
-        None if ho3 is None else unview(ho3, shift.dtype),
+    The leaf's shape alone chooses the view (``fused_view``). Where the
+    leaf has a native row-chunk view (module docstring), the words are read
+    as (rows/k, C) and each f32 tensor as (k, rows/k, C): reshapes of the
+    leaf's own layout, bitcasts on the TPU. Otherwise every tensor is
+    flattened, padded to whole word blocks and sliced back after. Both
+    views hand the kernel the same elements at the same word positions, so
+    their outputs are bit-identical."""
+    return _fused_unpack_in(
+        _leaf_views, words, param, opt, scalars, shift,
+        kernel=kernel, bits=bits, n_summed=n_summed, interpret=interpret,
     )
 
 

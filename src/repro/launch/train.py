@@ -19,6 +19,7 @@ between successive loss reads.
 from __future__ import annotations
 
 import argparse
+import math
 import time
 
 import jax
@@ -28,17 +29,35 @@ from repro.checkpoint import CheckpointStore
 from repro.configs import ShapeConfig, get_arch, smoke_config
 from repro.core import make_compressor, with_wire
 from repro.data.synthetic import SyntheticLMData
+from repro.kernels.ops import fused_view
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.step import build_init_state, build_train_step
 from repro.models.transformer import init_lm_params
 from repro.optim import adamw, sgd
+from repro.optim.base import FUSED_STATE_TENSORS
 from repro.optim.schedules import constant, warmup_wrap
 from repro.parallel.collectives import mesh_from_counts
-from repro.wire import wire_format_names
+from repro.wire import PackedInt, wire_format_names
 from repro.wire.bucketing import DEFAULT_BUCKET_WORDS
 
 # steps start+5 .. start+9 are profiled: past the compiles and the warmup
 PROFILE_FIRST, PROFILE_STEPS = 5, 5
+
+
+def fused_view_line(shapes, *, bits: int, n_tensors: int) -> str:
+    """How many parameters of leaves of `shapes` the packed fused kernel
+    updates in their own layout, and how many leaves it pads
+    (``kernels.ops.fused_view``)."""
+    native = total = padded = 0
+    for shape in shapes:
+        size = math.prod(shape)
+        total += size
+        if fused_view(shape, bits=bits, n_tensors=n_tensors) == "native":
+            native += size
+        else:
+            padded += 1
+    return (f"[train] fused view: {native} of {total} in place, "
+            f"{padded} leaves padded")
 
 
 def train_loop(
@@ -99,6 +118,14 @@ def train_loop(
             cfg, mesh, compressor=comp, base_opt=opt, fused=fused
         )
         opt_state, comp_state = init(params)
+    if fused and isinstance(comp.wire_format, PackedInt):
+        local = [sh.shard_shape(st.shape) for st, sh in zip(
+            jax.tree.leaves(art.arg_structs[0]),
+            jax.tree.leaves(art.in_shardings[0]), strict=True)]
+        n_tensors = (1 + len(FUSED_STATE_TENSORS[opt.fused_kernel])
+                     + (comp.fused_shift(comp_state) is not None))
+        print(fused_view_line(
+            local, bits=comp.wire_format.bits, n_tensors=n_tensors))
 
     data = SyntheticLMData(
         cfg.vocab, shape.seq_len, shape.global_batch, seed=seed

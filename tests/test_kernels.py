@@ -84,7 +84,12 @@ def test_unpack_words_matches_oracle_after_sum(shape, bits):
     )
 
 
-@pytest.mark.parametrize("shape", [(64,), (513, 300)])
+# (64,) and (513, 300) take the padded view, the others their native rows
+FUSED_UNPACK_SHAPES = [(64,), (513, 300), (64, 256), (3, 64, 128),
+                       (2, 32, 960)]
+
+
+@pytest.mark.parametrize("shape", FUSED_UNPACK_SHAPES)
 def test_fused_unpack_update_matches_oracle(shape):
     """The packed-wire fused kernel == unpack + fused-update composition."""
     n, bits = 4, 8
@@ -136,7 +141,7 @@ def _adamw_scalars(*, inv_nalpha, clip, lr, b1, b2, eps, wd, t):
     ])
 
 
-@pytest.mark.parametrize("shape", [(64,), (513, 300)])
+@pytest.mark.parametrize("shape", FUSED_UNPACK_SHAPES)
 @pytest.mark.parametrize("with_shift", [False, True])
 def test_fused_unpack_adamw_matches_oracle(shape, with_shift):
     """fused_unpack_adamw_2d == unpack + bias-corrected AdamW composition,
@@ -174,6 +179,91 @@ def test_fused_unpack_adamw_matches_oracle(shape, with_shift):
         np.testing.assert_allclose(got_h, want_h, rtol=1e-5, atol=1e-6)
     else:
         assert got_h is None
+
+
+@pytest.mark.parametrize("shape", [(64, 256), (3, 64, 128), (2, 32, 960)])
+@pytest.mark.parametrize("kernel,with_shift",
+                         [("sgd", False), ("sgd", True), ("adamw", True)])
+def test_fused_unpack_views_bit_identical(shape, kernel, with_shift):
+    """The native row-chunk view and the padded image view of one leaf give
+    the same p', state' and shift', bit for bit. Inputs and scalars are
+    short dyadic numbers, so every product is exact and no rounding depends
+    on whether the compiler contracts a multiply and add into an FMA (the
+    CPU's XLA does so differently in each fusion); what is compared is
+    which element meets which word field."""
+    n, bits = 4, 8
+    n_tensors = 2 + (kernel == "adamw") + with_shift
+    assert ops.fused_view(shape, bits=bits, n_tensors=n_tensors) == "native"
+    key = jax.random.PRNGKey(17)
+    lim = ref._INT_LIM[bits] // n
+    ints = jax.random.randint(key, (n, *shape), -lim, lim + 1)
+    wsum = sum(ops.pack_words(ints[i], bits=bits, n_workers=n)
+               for i in range(n))
+    dyadic = lambda i, lo, hi, scale: jax.random.randint(
+        jax.random.fold_in(key, i), shape, lo, hi).astype(jnp.float32) / scale
+    p = dyadic(0, -64, 64, 16)
+    opt = (dyadic(1, -64, 64, 16),)
+    if kernel == "adamw":
+        opt += (dyadic(2, 0, 64, 64),)
+        # inv_nalpha, clip, lr, b1, 1-b1, b2, 1-b2, eps, wd, bc1, bc2
+        sc = [2**-10, 0.5, 0.25, 0.5, 0.5, 0.75, 0.25, 2**-10, 2**-6,
+              0.5, 0.25]
+    else:  # inv_nalpha, clip, lr, mu, wd
+        sc = [2**-10, 0.5, 0.25, 0.5, 2**-6]
+    sc = jnp.array(sc, jnp.float32)
+    h = dyadic(3, -64, 64, 16) if with_shift else None
+    kw = dict(kernel=kernel, bits=bits, n_summed=n)
+    native = ops.fused_unpack_apply(wsum, p, opt, sc, h, **kw)
+    padded = ops._fused_unpack_in(ops._padded_views, wsum, p, opt, sc, h,
+                                  **kw)
+    for got, want in zip(jax.tree.leaves(native), jax.tree.leaves(padded),
+                         strict=True):
+        assert got.shape == shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_fused_view_by_shape():
+    """A 1-D leaf, rows not a multiple of k and rows/k not a multiple of 8
+    take the padded view; whole row chunks of 8k rows the native one, at
+    any width that a block fits."""
+    view = lambda shape, bits=8: ops.fused_view(shape, bits=bits)
+    assert view((4096,)) == "padded"
+    assert view((30, 128)) == "padded"  # rows % 4
+    assert view((3, 36, 128)) == "padded"  # rows/k = 27
+    assert view((32, 128)) == "native"
+    assert view((2, 3840, 960)) == "native"  # C not a multiple of 128
+    assert view((64, 128), bits=4) == "native"  # k = 8: rows/k = 8
+    assert view((32, 128), bits=4) == "padded"  # rows/k = 4
+    assert view((16, 2**20)) == "padded"  # no block of 8 rows fits
+
+
+def test_fused_view_line_at_danube_widths():
+    """h2o-danube-3-4b at its published widths and 2 layers, as the chip
+    benchmark runs it: every leaf but the three norms takes its native
+    view, on the SGD kernel with or without a shift, and on AdamW's."""
+    import json
+    import pathlib
+
+    from repro.configs.base import ModelConfig
+    from repro.launch.train import fused_view_line
+    from repro.models.transformer import init_lm_params
+
+    path = (pathlib.Path(__file__).parents[1]
+            / "benchmarks/chip/configs/h2o-danube-3-4b.json")
+    c = json.loads(path.read_text())
+    cfg = ModelConfig(**{k: c[k] for k in (
+        "name", "family", "n_layers", "d_model", "n_heads", "n_kv_heads",
+        "d_ff", "vocab", "head_dim", "window", "rope_theta",
+        "tie_embeddings", "qkv_bias")})
+    params = jax.eval_shape(
+        lambda k: init_lm_params(k, cfg, tp=1, n_shards=1,
+                                 dtype=jnp.float32), jax.random.PRNGKey(0))
+    shapes = [x.shape for x in jax.tree.leaves(params)]
+    assert len(shapes) == 12
+    for n_tensors in (2, 3, 4):
+        assert fused_view_line(shapes, bits=8, n_tensors=n_tensors) == (
+            "[train] fused view: 555417600 of 555436800 in place, "
+            "3 leaves padded")
 
 
 @given(st.integers(1, 3000), st.integers(1, 60), st.integers(0, 2**31 - 1))
