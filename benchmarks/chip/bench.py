@@ -9,10 +9,14 @@ metric or kernel sits in a file of its own, found by the name that
   cells/<workload>.json    the limits of the check that decides `correct`
   metrics/<metric>.py      read(ctx) -> number or None, for a per-layer metric
   costs/<kernel>.py        cost(...) -> (operations, bytes) from shapes
+  families/<family>.py     a model family, by the configuration's "family":
+                           the plain reference model (init_params(c, key),
+                           loss_fn(params, tokens, labels, c, mm)) and
+                           ops_per_token(c, seq)
   peaks.json               the chips' peaks, by device_kind
 
-So a later change adds a cell, a configuration or a metric by adding files
-and entries, and edits none of these.
+So a later change adds a cell, a configuration, a model family or a metric
+by adding files and entries, and edits none of these.
 """
 from __future__ import annotations
 
@@ -20,6 +24,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -102,6 +107,15 @@ def reader(metric: str):
 def cost(kernel: str):
     """The kernel's cost function: (operations, bytes) from shapes."""
     return _module("costs", kernel).cost
+
+
+def family(name: str):
+    """The model family's module, ``families/<name>.py``, loaded once a
+    process, so that a family that takes another's functions shares them."""
+    key = f"_bench_families_{name}"
+    if key not in sys.modules:
+        sys.modules[key] = _module("families", name)
+    return sys.modules[key]
 
 
 def peaks(device_kind: str) -> dict:

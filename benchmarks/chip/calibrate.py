@@ -9,12 +9,13 @@ takes them in set-up, against the reference: the lower readings. For every
 seed of ``--control-seeds``, in the program's place: the control (the
 reference with its matrix products in float8, one precision below the
 bfloat16 the configuration computes in), and the faults a training cell can
-have on one chip, planted in the reference: half of the batch left out.
-A state left unchanged
-reads 1 on ``change`` and needs no run. One process compiles the program
-once for all seeds. Prints one JSON line per reading and a summary line
-(the largest lower reading and the smallest reading of each kind, per
-number); ``--out`` writes them to a file too.
+have, planted in the reference: half of the batch left out, and on more
+than one worker the exchange left out (``no_exchange``: each worker decodes
+only its own integers). A state left unchanged reads 1 on ``change`` and
+needs no run. One process compiles the program once for all seeds. Prints
+one JSON line per reading and a summary line (the largest lower reading
+and the smallest reading of each kind, per number); ``--out`` writes them
+to a file too.
 """
 from __future__ import annotations
 
@@ -74,6 +75,8 @@ def calibrate(cell, lower_seeds, control_seeds, *, out=None):
         lines.append(line)
         print(json.dumps(line), flush=True)
 
+    faults = ("float8", "half_batch") + (
+        ("no_exchange",) if cell.traffic["data_parallel"] > 1 else ())
     prog = Program(cell.config, cell.traffic, cell.chips, lower_seeds[0])
     batches = {}
     for seed in lower_seeds + [s for s in control_seeds
@@ -93,7 +96,7 @@ def calibrate(cell, lower_seeds, control_seeds, *, out=None):
                 print(json.dumps({"leaf_names": ref["leaves"]}), flush=True)
         if seed not in control_seeds:
             continue
-        for kind in ("float8", "half_batch"):
+        for kind in faults:
             t0 = time.perf_counter()
             got = check.reference_readings(
                 cell, seed, readings["batches"],

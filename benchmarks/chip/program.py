@@ -12,6 +12,7 @@ steps ahead and reads each loss later (``batches``, ``dispatch``, ``read``).
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 import time
@@ -32,13 +33,14 @@ from repro.optim import sgd  # noqa: E402
 from repro.optim.schedules import constant, warmup_wrap  # noqa: E402
 from repro.parallel.collectives import mesh_from_counts  # noqa: E402
 
-MODEL_KEYS = ("name", "family", "n_layers", "d_model", "n_heads",
-              "n_kv_heads", "d_ff", "vocab", "head_dim", "window",
-              "rope_theta", "tie_embeddings", "qkv_bias")
-
 
 def model_config(c: dict) -> ModelConfig:
-    return ModelConfig(**{k: c[k] for k in MODEL_KEYS})
+    """The program's configuration: every key of the configuration file
+    that names a ``ModelConfig`` field, but the provenance string
+    ``source``. The file's other keys (``param_dtype``, ``published``,
+    ``reduced``, ``assumed``, ``deployment``, ...) stay the benchmark's."""
+    fields = {f.name for f in dataclasses.fields(ModelConfig)} - {"source"}
+    return ModelConfig(**{k: v for k, v in c.items() if k in fields})
 
 
 def prng_seed(seed: int) -> int:

@@ -2,11 +2,11 @@
 IntSGD's encode, integer sum and decode, the global-norm clip and momentum
 SGD, in straightforward ``jax.numpy`` at float32.
 
-It imports nothing of the program under test. It makes its own weights from
-the seed by the recipe the configuration's model uses (uniform in
-±1/sqrt(fan_in), ones for the norm weights, one PRNG key per tensor split in
-the model's order), and runs the same data-parallel algorithm worker by
-worker on one device:
+It imports nothing of the program under test. The model is the
+configuration's family's (``families/<family>.py``, by the ``family`` key):
+its own weights from the seed (``init_params``) and its loss
+(``loss_fn``). This file holds what every family shares, and runs the same
+data-parallel algorithm worker by worker on one device:
 
   step 0      exact: the mean of the workers' float gradients;
   step k > 0  IntSGD (Alg. 1): α = sqrt(d) / sqrt(2 n r / η² + ε²),
@@ -25,7 +25,6 @@ Matrix products go through one function of ``MATMULS``: float32 at the
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -33,9 +32,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+import bench
+
 HIGHEST = lax.Precision.HIGHEST
-QUERY_BLOCK = 512  # attention rows computed together; bounds the score tile
-NEG_INF = -1e30
 F8_MAX = 448.0  # largest finite float8_e4m3fn
 
 
@@ -74,128 +73,6 @@ def _mm_f8_bwd(res, ct):
 _mm_f8.defvjp(_mm_f8_fwd, _mm_f8_bwd)
 
 MATMULS = {"float32": _mm_f32, "float8": _mm_f8}
-
-
-# ---------------------------------------------------------------------------
-# weights from the seed
-# ---------------------------------------------------------------------------
-def _uniform(key, shape, fan_in):
-    s = 1.0 / math.sqrt(fan_in)
-    return jax.random.uniform(key, shape, jnp.float32, -s, s)
-
-
-def _layer(key, c):
-    d, f = c["d_model"], c["d_ff"]
-    q, kv = c["n_heads"] * c["head_dim"], c["n_kv_heads"] * c["head_dim"]
-    k_attn, k_mlp = jax.random.split(key, 2)
-    ka = jax.random.split(k_attn, 4)
-    km = jax.random.split(k_mlp, 3)
-    return {
-        "ln1": jnp.ones((d,), jnp.float32),
-        "ln2": jnp.ones((d,), jnp.float32),
-        "attn": {
-            "wq": _uniform(ka[0], (d, q), d),
-            "wk": _uniform(ka[1], (d, kv), d),
-            "wv": _uniform(ka[2], (d, kv), d),
-            "wo": _uniform(ka[3], (q, d), q),
-        },
-        "mlp": {
-            "w_gate": _uniform(km[0], (d, f), d),
-            "w_up": _uniform(km[1], (d, f), d),
-            "w_down": _uniform(km[2], (f, d), f),
-        },
-    }
-
-
-def init_params(c, key):
-    """The configuration's weights from a PRNG key, layers stacked."""
-    d, v = c["d_model"], c["vocab"]
-    keys = jax.random.split(key, 8)
-    p = {
-        "embed": _uniform(keys[0], (v, d), d),
-        "ln_f": jnp.ones((d,), jnp.float32),
-    }
-    if not c["tie_embeddings"]:
-        p["lm_head"] = _uniform(keys[1], (d, v), d)
-    layers = [_layer(k, c) for k in jax.random.split(keys[2], c["n_layers"])]
-    p["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
-    return p
-
-
-# ---------------------------------------------------------------------------
-# the model
-# ---------------------------------------------------------------------------
-def _rmsnorm(x, w, eps=1e-6):
-    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def _rope(x, pos, theta):
-    """Rotate the two halves of each head (x: B, T, H, dh; pos: T)."""
-    half = x.shape[-1] // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos[:, None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-
-
-def _attention(p, x, c, mm):
-    b, t, _ = x.shape
-    nq, nkv, dh = c["n_heads"], c["n_kv_heads"], c["head_dim"]
-    group = nq // nkv
-    pos = jnp.arange(t, dtype=jnp.int32)
-    q = _rope(mm(x, p["wq"]).reshape(b, t, nq, dh), pos, c["rope_theta"])
-    k = _rope(mm(x, p["wk"]).reshape(b, t, nkv, dh), pos, c["rope_theta"])
-    v = mm(x, p["wv"]).reshape(b, t, nkv, dh)
-    # query head h reads key/value head h // group
-    k = jnp.repeat(k, group, axis=2)
-    v = jnp.repeat(v, group, axis=2)
-    window = c.get("window")
-
-    @jax.checkpoint
-    def rows(qb, qpos):
-        s = jnp.einsum("bqhd,bkhd->bhqk", qb, k, precision=HIGHEST)
-        s = s / math.sqrt(dh)
-        ok = pos[None, :] <= qpos[:, None]
-        if window is not None:
-            ok &= pos[None, :] > qpos[:, None] - window
-        s = jnp.where(ok[None, None], s, NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", w, v, precision=HIGHEST)
-
-    blk = min(QUERY_BLOCK, t)
-    out = jnp.concatenate(
-        [rows(q[:, i:i + blk], pos[i:i + blk]) for i in range(0, t, blk)],
-        axis=1,
-    )
-    return mm(out.reshape(b, t, nq * dh), p["wo"])
-
-
-def _mlp(p, x, mm):
-    return mm(jax.nn.silu(mm(x, p["w_gate"])) * mm(x, p["w_up"]), p["w_down"])
-
-
-def loss_fn(params, tokens, labels, c, mm):
-    """Mean next-token cross entropy over the positions whose label >= 0."""
-    x = params["embed"][tokens]
-    for i in range(c["n_layers"]):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-
-        @jax.checkpoint
-        def layer(x, lp):
-            h = x + _attention(lp["attn"], _rmsnorm(x, lp["ln1"]), c, mm)
-            return h + _mlp(lp["mlp"], _rmsnorm(h, lp["ln2"]), mm)
-
-        x = layer(x, lp)
-    h = _rmsnorm(x, params["ln_f"])
-    head = params["embed"].T if c["tie_embeddings"] else params["lm_head"]
-    logits = mm(h, head)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(
-        logits, jnp.clip(labels, 0, None)[..., None], axis=-1
-    )[..., 0]
-    mask = (labels >= 0).astype(jnp.float32)
-    return jnp.sum((logz - picked) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -239,14 +116,18 @@ class Trainer:
 
     def __init__(self, c, job, *, precision="float32"):
         self.c, self.job = c, job
+        model = bench.family(c["family"])
         mm = MATMULS[precision]
-        grad = jax.value_and_grad(partial(loss_fn, c=c, mm=mm))
+        grad = jax.value_and_grad(partial(model.loss_fn, c=c, mm=mm))
         self.grad = jax.jit(grad)
-        self.init = jax.jit(partial(init_params, c))
+        self.init = jax.jit(partial(model.init_params, c))
         self.add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
                            donate_argnums=0)
         self.encode = jax.jit(self._encode, donate_argnums=0)
-        self.update = jax.jit(self._update, donate_argnums=(0, 1))
+        self.own_share = jax.jit(self._own_share, donate_argnums=0,
+                                 static_argnums=(5, 6))
+        self.update = jax.jit(self._update, donate_argnums=(0, 1),
+                              static_argnames="clip")
         self.norms = jax.jit(lambda t: [jnp.sqrt(s) for s in _leaf_sq(t)])
         wd = job["weight_decay"]
         self.grad0 = jax.jit(
@@ -260,10 +141,30 @@ class Trainer:
         ints = [_int_image(x, alpha, seeds[i], lim) for i, x in enumerate(leaves)]
         return jax.tree.map(jnp.add, acc, jax.tree.unflatten(tdef, ints))
 
-    def _update(self, x, m, ghat, eta, r):
-        j = self.job
+    def _clip_scale(self, ghat):
         sq = sum(_leaf_sq(ghat))
-        scale = jnp.minimum(1.0, j["clip_norm"] / (jnp.sqrt(sq) + 1e-12))
+        return jnp.minimum(1.0, self.job["clip_norm"] / (jnp.sqrt(sq) + 1e-12))
+
+    def _own_share(self, acc, g, alpha, seeds, lim, w, n):
+        """acc plus worker w's gradient decoded from its own integers alone
+        and clipped by its own norm, on the rows of each leaf that worker w
+        updates under ZeRO-1 (the flat leaf padded to n equal rows)."""
+        zero = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.int32), g)
+        ghat = jax.tree.map(lambda s: s.astype(jnp.float32) * (1.0 / (n * alpha)),
+                            self._encode(zero, g, alpha, seeds, lim))
+        scale = self._clip_scale(ghat)
+
+        def rows(a, x):
+            per = -(-x.size // n)
+            i = lax.broadcasted_iota(jnp.int32, (x.size,), 0)
+            mine = (i >= w * per) & (i < (w + 1) * per)
+            return a + jnp.where(mine, x.reshape(-1) * scale, 0.0).reshape(x.shape)
+
+        return jax.tree.map(rows, acc, ghat)
+
+    def _update(self, x, m, ghat, eta, r, clip=True):
+        j = self.job
+        scale = self._clip_scale(ghat) if clip else 1.0
         mu, wd = j["momentum"], j["weight_decay"]
         m = jax.tree.map(lambda m, g, p: mu * m + g * scale + wd * p, m, ghat, x)
         new = jax.tree.map(lambda p, m: p - eta * m, x, m)
@@ -283,9 +184,12 @@ def run(trainer, key, batches, *, n_workers, compressed, noise_seed=0,
     ``batches[k]`` is step k's global (tokens, labels) as numpy arrays, rows
     split evenly over the n workers. ``compressed(k)`` says whether step k
     is an IntSGD step; ``noise_seed`` keys the rounding noise. ``fault``
-    plants the fault of a one-chip cell that the benchmark's check has to
-    catch: "half_batch" (half of each row's tokens left out, the mean taken
-    over the rest).
+    plants a fault that the benchmark's check has to catch: "half_batch"
+    (half of each row's tokens left out, the mean taken over the rest), or
+    "no_exchange" (the workers' integers never summed: on each IntSGD step
+    each worker decodes only its own, clips that by its own norm and
+    updates with it the ZeRO-1 rows it owns, which the all-gather then
+    puts together).
 
     Returns the per-step losses (the workers' mean, as the program reports
     it), the leaf norms of the first gradient as the optimizer receives it
@@ -310,6 +214,7 @@ def run(trainer, key, batches, *, n_workers, compressed, noise_seed=0,
             alpha = jnp.sqrt(jnp.float32(d)) / jnp.sqrt(
                 2.0 * n_workers * r / jnp.square(eta) + 1e-16
             )
+        own = alpha is not None and fault == "no_exchange"
         acc, loss = None, 0.0
         for w in range(n_workers):
             lw, g = trainer.grad(x, jnp.asarray(tokens[rows[w]]),
@@ -319,20 +224,26 @@ def run(trainer, key, batches, *, n_workers, compressed, noise_seed=0,
                 acc = g if acc is None else trainer.add(acc, g)
             else:
                 if acc is None:
-                    acc = jax.tree.map(
-                        lambda a: jnp.zeros(a.shape, jnp.int32), g)
+                    dtype = jnp.float32 if own else jnp.int32
+                    acc = jax.tree.map(lambda a: jnp.zeros(a.shape, dtype), g)
                 seeds = _step_seeds(noise_seed, k, w, len(jax.tree.leaves(g)))
-                acc = trainer.encode(acc, g, alpha, seeds, lim)
+                if own:
+                    acc = trainer.own_share(acc, g, alpha, seeds, lim, w,
+                                            n_workers)
+                else:
+                    acc = trainer.encode(acc, g, alpha, seeds, lim)
             del g
         if alpha is None:
             ghat = jax.tree.map(lambda a: a / n_workers, acc)
+        elif own:
+            ghat = acc
         else:
             ghat = jax.tree.map(
                 lambda s: s.astype(jnp.float32) * (1.0 / (n_workers * alpha)),
                 acc)
         del acc
         losses.append(loss / n_workers)
-        x, m, r = trainer.update(x, m, ghat, eta, r)
+        x, m, r = trainer.update(x, m, ghat, eta, r, clip=not own)
         del ghat
         if k == 0:
             # the optimizer's state after one step is m = ĝ0 + λ x0

@@ -13,6 +13,10 @@ its root, which is the fusion's own ``op_name``; ``straddling`` lists the
 fusions whose body holds instructions of more than one stage. An operation
 with no ``op_name``, or none of the stages in it (a ``copy`` XLA inserted,
 the loss's mean), is ``other``.
+
+``scope_ms`` reads any scope at any depth, loop bodies included, for a
+per-layer metric of one mechanism inside a stage (a model's routing or
+experts inside ``fwd_bwd``).
 """
 from __future__ import annotations
 
@@ -34,9 +38,14 @@ _NAME = re.compile(r"%[\w.\-]+")
 _KERNEL_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
 
 
+def _components(op_name: str) -> list:
+    """The path components of an ``op_name`` below ``jit(..)/``."""
+    return layers._PREFIX.sub("", op_name).split("/")
+
+
 def stage_of_name(op_name: str) -> str:
     """The outermost stage component of an ``op_name``, else ``other``."""
-    for part in layers._PREFIX.sub("", op_name).split("/"):
+    for part in _components(op_name):
         if part in STAGES:
             return part
     return OTHER
@@ -96,6 +105,36 @@ def ms(ctx, names) -> float | None:
     value = layers.ms_per_step(
         ctx, lambda c, n: top_level(c, n) and stage_of(c, n) in names)
     return value or 0.0
+
+
+def scope_ms(ctx, scope: str) -> float | None:
+    """Device time per step, averaged over chips, of the traced operations
+    whose ``op_name`` has `scope` as a path component, at any depth: a
+    stage, or a scope inside one (``checkpoint``, a model's own
+    ``jax.named_scope``), loop bodies included. Each operation counts once:
+    an event that lies inside another counted event of its chip (a body
+    operation inside its loop's event) counts within it. As
+    ``layers.ms_per_step`` does, an event counts by its start: a loop that
+    began before the window counts not at all, nor do its body's events.
+    0.0 where the instructions carry the scope and none ran in the window;
+    None where no instruction of the compiled step carries it, so that a
+    renamed scope fails the run."""
+    named = {n for n, i in ctx["instrs"].items()
+             if scope in _components(i.get("op_name", ""))}
+    if not named:
+        return None
+    a, b = ctx["trace"].window
+    total = 0.0
+    for ops in ctx["trace"].devices:
+        end = None
+        for o in sorted((o for o in ops if o.name in named),
+                        key=lambda o: (o.start, -o.end)):
+            if end is not None and o.end <= end:
+                continue  # inside the counted event before it
+            end = o.end
+            if a <= o.start < b:
+                total += o.end - o.start
+    return total / len(ctx["trace"].devices) / ctx["steps"] * 1e3
 
 
 def split(ctx) -> dict:

@@ -22,6 +22,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 CHIP = os.path.dirname(HERE)
 sys.path[:0] = [CHIP, HERE]
 
+import bench  # noqa: E402
 import check  # noqa: E402
 import reference  # noqa: E402
 import run  # noqa: E402
@@ -40,7 +41,7 @@ def test_reference_weights_are_the_programs():
     from repro.models.transformer import init_lm_params
 
     key = jax.random.PRNGKey(7)
-    ours = reference.init_params(tiny.CONFIG, key)
+    ours = bench.family("dense").init_params(tiny.CONFIG, key)
     theirs = init_lm_params(key, model_config(tiny.CONFIG), dtype=jnp.float32)
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(a, b),
                  ours, theirs)
@@ -51,14 +52,15 @@ def test_reference_loss_is_the_models_at_float32():
     from repro.models.transformer import lm_loss
 
     cfg = model_config(tiny.CONFIG)
-    params = reference.init_params(tiny.CONFIG, jax.random.PRNGKey(3))
+    dense = bench.family("dense")
+    params = dense.init_params(tiny.CONFIG, jax.random.PRNGKey(3))
     toks = jax.random.randint(jax.random.PRNGKey(4), (2, 32), 0, 256)
     labels = jnp.roll(toks, -1, axis=1).at[:, -1].set(-1)
     with jax.default_matmul_precision("highest"):
         want = lm_loss(params, {"tokens": toks, "labels": labels}, Axes(),
                        cfg, dtype=jnp.float32)
-        got = reference.loss_fn(params, toks, labels, tiny.CONFIG,
-                                reference.MATMULS["float32"])
+        got = dense.loss_fn(params, toks, labels, tiny.CONFIG,
+                            reference.MATMULS["float32"])
     np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
 
 
@@ -150,18 +152,62 @@ DP4 = """
 import json, sys
 sys.path[:0] = [{chip!r}, {here!r}]
 import run, tiny
+hook = None
+{hook}
 r = run.run_cell(tiny.cell(chips=4), {seed}, 0.5, False,
-                 device=dict(platform="cpu", kind="cpu", count=4))
+                 device=dict(platform="cpu", kind="cpu", count=4),
+                 program_hook=hook)
 print(json.dumps({{"correct": r["correct"], "checks": r["checks"]}}))
 """
 
+# the integer all-reduce left out: each worker unpacks its own words alone
+NO_EXCHANGE = """
+def hook(prog):
+    from repro.parallel import collectives
+    collectives.psum_wire_words = lambda words, axes: words
+"""
 
-def test_four_workers_agree_with_the_reference():
+
+def _four_workers(hook=""):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    code = DP4.format(chip=CHIP, here=HERE, seed=SEED)
+    code = DP4.format(chip=CHIP, here=HERE, seed=SEED, hook=hook)
     p = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
-    r = json.loads(p.stdout.strip().splitlines()[-1])
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_four_workers_agree_with_the_reference():
+    r = _four_workers()
     assert r["correct"] is True, r["checks"]
+
+
+def test_four_workers_without_the_exchange_are_caught():
+    r = _four_workers(NO_EXCHANGE)
+    assert r["correct"] is False, r["checks"]
+
+
+def _batches(rng, rows, seq=32, steps=3):
+    out = []
+    for _ in range(steps):
+        tokens = rng.integers(0, tiny.CONFIG["vocab"], (rows, seq), np.int32)
+        labels = np.roll(tokens, -1, axis=1)
+        labels[:, -1] = -1
+        out.append((tokens, labels))
+    return out
+
+
+def test_reference_without_the_exchange_fails_the_limits():
+    """The reference's own "no_exchange" fault, as calibrate.py reads it
+    for a cell on four chips, on three seeds."""
+    cell = tiny.cell(chips=4)
+    for seed in (11, 12, 13):
+        batches = _batches(np.random.default_rng(seed), 8)
+        ref = check.reference_readings(cell, seed, batches)
+        got = check.reference_readings(cell, seed, batches,
+                                       fault="no_exchange")
+        judged = check.judge(cell, check.numbers(got, ref))
+        assert not all(c["ok"] for c in judged.values()), judged
+        # the exact first step exchanges floats and is not at fault
+        assert got["grad0"] == ref["grad0"]

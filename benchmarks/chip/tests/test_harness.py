@@ -62,6 +62,29 @@ def test_window_bounds_the_attention_count():
     assert windowed < full
 
 
+def test_a_missing_family_names_its_file():
+    with pytest.raises(bench.BenchmarkError,
+                       match="benchmarks/chip/families/nosuch.py"):
+        bench.family("nosuch")
+
+
+# the keys the program's configuration was built from before every
+# ModelConfig field of the file was passed through
+DENSE_KEYS = ("name", "family", "n_layers", "d_model", "n_heads",
+              "n_kv_heads", "d_ff", "vocab", "head_dim", "window",
+              "rope_theta", "tie_embeddings", "qkv_bias")
+
+
+@pytest.mark.parametrize("name", ["granite-8b", "h2o-danube-3-4b"])
+def test_dense_model_configs_are_unchanged(name):
+    sys.path.insert(0, os.path.join(ROOT, "src"))
+    from program import model_config
+    from repro.configs.base import ModelConfig
+
+    c = _json(CHIP, "configs", f"{name}.json")
+    assert model_config(c) == ModelConfig(**{k: c[k] for k in DENSE_KEYS})
+
+
 def test_unknown_device_has_no_peaks():
     assert bench.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(bench.BenchmarkError, match="no peaks"):
@@ -221,41 +244,91 @@ def _digests(root):
     return out
 
 
+TOY_FAMILY = """\
+import bench
+
+_dense = bench.family("dense")
+init_params, loss_fn = _dense.init_params, _dense.loss_fn
+
+
+def ops_per_token(c, seq):
+    return 2 * _dense.ops_per_token(c, seq) + c["n_experts"]
+"""
+
+FOUND = """\
+import json, sys
+import numpy as np
+sys.path.insert(0, 'benchmarks/chip')
+import bench, check, program
+c = bench.load_cell('tiny-cell')
+toy = bench.load_cell('toy-cell')
+mc = program.model_config(toy.config)
+rng = np.random.default_rng(5)
+batches = []
+for _ in range(3):
+    t = rng.integers(0, 256, (2, 32), np.int32)
+    batches.append((t, np.roll(t, -1, axis=1)))
+tokens = toy.traffic['batch_per_chip'] * toy.traffic['seq_len']
+print(json.dumps({
+    'tiny': [c.config['d_model'], c.traffic['seq_len'], c.limits['loss']],
+    'per_layer': [m['name'] for m in c.per_layer],
+    'steps_traced': bench.reader('steps_traced')({'steps': 3}),
+    'model_config': [mc.family, mc.n_experts, mc.top_k, mc.n_shared_experts,
+                     mc.kv_lora],
+    'cost': bench.cost('train_step')(toy.config, toy.traffic, 1)[0],
+    'dense_ops': bench.family('dense').ops_per_token(toy.config, 32) * tokens,
+    'toy_ref': check.reference_readings(toy, 7, batches),
+    'dense_ref': check.reference_readings(c, 7, batches),
+}))
+"""
+
+
 def test_new_cell_and_metric_are_found_without_editing(tmp_path):
+    """A cell, a configuration of a new family, its family file and a
+    per-layer metric join as new files and entries: no file the benchmark
+    has changes. The program keeps the family's keys, the cost counts the
+    family's operations, and the reference runs the family's model."""
     root = tmp_path / "checkout"
     shutil.copytree(CHIP, root / "benchmarks" / "chip",
                     ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root / "BENCHMARK.json")
     before = _digests(root / "benchmarks" / "chip")
     chip = root / "benchmarks" / "chip"
+    toy = dict(tiny.CONFIG, name="toy", family="toy", n_experts=8, top_k=2,
+               n_shared_experts=1, kv_lora=32)
     (chip / "configs" / "tiny.json").write_text(json.dumps(tiny.CONFIG))
+    (chip / "configs" / "toy.json").write_text(json.dumps(toy))
+    (chip / "families" / "toy.py").write_text(TOY_FAMILY)
     (chip / "traffic" / "tiny_job.json").write_text(json.dumps(tiny.TRAFFIC))
-    (chip / "cells" / "tiny-cell.json").write_text(
-        json.dumps({"limits": tiny.LIMITS}))
+    for cell in ("tiny-cell", "toy-cell"):
+        (chip / "cells" / f"{cell}.json").write_text(
+            json.dumps({"limits": tiny.LIMITS}))
     (chip / "metrics" / "steps_traced.py").write_text(
         "def read(ctx):\n    return ctx['steps']\n")
     b = json.loads((root / "BENCHMARK.json").read_text())
-    b["configs"].append({"name": "tiny", "source": "a test", "reduced": [],
-                         "file": "benchmarks/chip/configs/tiny.json",
-                         "why": "test"})
-    b["workloads"].append({"name": "tiny-cell", "config": "tiny",
-                           "traffic": "tiny_job", "chips": 1, "why": "test"})
+    for name in ("tiny", "toy"):
+        b["configs"].append({"name": name, "source": "a test", "reduced": [],
+                             "file": f"benchmarks/chip/configs/{name}.json",
+                             "why": "test"})
+        b["workloads"].append({"name": f"{name}-cell", "config": name,
+                               "traffic": "tiny_job", "chips": 1,
+                               "why": "test"})
     b["per_layer"].append({"name": "steps_traced", "unit": "steps",
                            "better": "higher", "source": "device_trace",
                            "layer": "device", "moves": "tokens_per_s",
                            "workloads": ["tiny-cell"]})
     (root / "BENCHMARK.json").write_text(json.dumps(b))
-    code = (
-        "import sys; sys.path.insert(0, 'benchmarks/chip'); import bench\n"
-        "c = bench.load_cell('tiny-cell')\n"
-        "print(c.config['d_model'], c.traffic['seq_len'], c.limits['loss'],"
-        " [m['name'] for m in c.per_layer],"
-        " bench.reader('steps_traced')({'steps': 3}))\n"
-    )
-    out = subprocess.run([sys.executable, "-c", code], cwd=root, check=True,
-                         capture_output=True, text=True).stdout.split()
-    assert out[:3] == ["64", "32", str(tiny.LIMITS["loss"])]
-    assert "'steps_traced']" in out[-2] and out[-1] == "3"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    p = subprocess.run([sys.executable, "-c", FOUND], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["tiny"] == [64, 32, tiny.LIMITS["loss"]]
+    assert "steps_traced" in out["per_layer"] and out["steps_traced"] == 3
+    assert out["model_config"] == ["toy", 8, 2, 1, 32]
+    assert out["cost"] == 2 * out["dense_ops"] + 8 * 64
+    assert out["toy_ref"] == out["dense_ref"]
     after = _digests(chip)
     assert all(after[k] == v for k, v in before.items())
 

@@ -2,8 +2,10 @@
 
     JAX_PLATFORMS=cpu python -m pytest benchmarks/chip/tests -q
 
-On one granite-8b step recorded on the chip (``data/granite_step.json.gz``,
-written by ``stage_split.py --record``) and on events laid out by hand.
+On steps recorded on the chip by ``stage_split.py --record`` (granite-8b on
+one chip, ``data/granite_step.json.gz``; danube, whose two layers run in a
+loop, ``data/danube_stages.json.gz``; granite on four chips, chip 0,
+``data/granite_dp4_step.json.gz``) and on events laid out by hand.
 """
 import copy
 import gzip
@@ -24,14 +26,17 @@ import tiny  # noqa: E402
 import trace_reduce as tr  # noqa: E402
 
 RECORDED = os.path.join(HERE, "data", "granite_step.json.gz")
+FIXTURES = {"granite": RECORDED,
+            "danube": os.path.join(HERE, "data", "danube_stages.json.gz"),
+            "granite_dp4": os.path.join(HERE, "data", "granite_dp4_step.json.gz")}
 READERS = {"fwd_bwd_ms": ("fwd_bwd",), "alpha_ms": ("alpha",),
            "encode_ms": ("encode",), "wire_ms": ("wire",),
            "update_ms": ("decode", "clip", "update")}
 
 
-def _recorded():
-    """A context as the harness builds it, from the recorded step."""
-    with gzip.open(RECORDED, "rt") as f:
+def _recorded(path=RECORDED):
+    """A context as the harness builds it, from a recorded step."""
+    with gzip.open(path, "rt") as f:
         fx = json.load(f)
     ops = sorted((tr.Op(n, s * 1e-9, e * 1e-9) for n, s, e in fx["ops"]),
                  key=lambda o: o.start)
@@ -134,6 +139,35 @@ def test_a_program_without_stage_names_reads_zero():
     assert stages.split(ctx)["other"] == pytest.approx(2.0)
 
 
+def test_a_scope_counts_each_operation_once():
+    """A loop's event holds its body's events; a scope counts an event once,
+    whether it matched the loop, the body or both, on each chip."""
+    named = {
+        "loop": ("main", "jit(step)/fwd_bwd/while"),
+        "b1": ("body", "jit(step)/fwd_bwd/while/body/checkpoint/dot_general"),
+        "b2": ("body", "jit(step)/fwd_bwd/while/body/checkpoint/add"),
+        "b3": ("body", "jit(step)/fwd_bwd/while/body/mul"),
+        "top": ("main", "jit(step)/fwd_bwd/checkpoint/exp"),
+        "upd": ("main", "jit(step)/shard_map/update/mul"),
+        "idle": ("main", "jit(step)/fwd_bwd/unused/neg"),
+    }
+    instrs = {n: {"opcode": "fusion", "arrays": [], "computation": comp,
+                  "op_name": o} for n, (comp, o) in named.items()}
+    ms = [("loop", 0, 3), ("b1", 0.5, 1), ("b2", 1, 1.5), ("b3", 2, 2.5),
+          ("top", 3, 4), ("upd", 4, 5)]
+    ops = [tr.Op(n, s * 1e-3, e * 1e-3) for n, s, e in ms]
+    trace = tr.Trace([ops, ops], [], (0.0, 5e-3))
+    ctx = {"trace": trace, "instrs": instrs, "steps": 1, "entry": "main"}
+    assert stages.scope_ms(ctx, "fwd_bwd") == pytest.approx(4.0)
+    assert stages.scope_ms(ctx, "while") == pytest.approx(3.0)
+    assert stages.scope_ms(ctx, "checkpoint") == pytest.approx(2.0)
+    assert stages.scope_ms(ctx, "update") == pytest.approx(1.0)
+    assert stages.scope_ms(ctx, "unused") == 0.0
+    assert stages.scope_ms(ctx, "nosuch") is None
+    assert stages.scope_ms(ctx, "fwd_bwd") == pytest.approx(
+        stages.ms(ctx, ("fwd_bwd",)))
+
+
 # ---------------------------------------------------------------------------
 # one granite step recorded on the chip
 # ---------------------------------------------------------------------------
@@ -205,3 +239,93 @@ def test_recorded_straddling_lists_each_fusion_once():
         assert ms >= 0
     assert [ms for *_, ms in found] == sorted((ms for *_, ms in found),
                                               reverse=True)
+
+
+# stages.split of each recorded step, pinned: a change to the stage readers
+# must leave these readings as they are
+RECORDED_SPLIT = {
+    "granite": {
+        "fwd_bwd": 116.699567, "alpha": 19.82236600000001,
+        "encode": 9.969351999999972, "wire": 7.782919999999985,
+        "decode": 0.0, "clip": 4.689072000000044,
+        "update": 43.39008800000005, "counters": 4.8066189999999285,
+        "other": 21.15027500000001},
+    "danube": {
+        "fwd_bwd": 76.15105000000005, "alpha": 5.759073000000003,
+        "encode": 5.259016999999977, "wire": 17.14379399999999,
+        "decode": 0.0, "clip": 0.0, "update": 13.613047999999988,
+        "counters": 3.3885770000000175, "other": 26.018367000000065},
+    "granite_dp4": {
+        "fwd_bwd": 114.86828200000005, "alpha": 6.320275000000014,
+        "encode": 10.75403799999998, "wire": 21.780076000000008,
+        "decode": 0.0, "clip": 2.332055000000027,
+        "update": 33.29943899999996, "counters": 2.1364379999999903,
+        "other": 43.873319000000066},
+}
+
+
+@pytest.mark.parametrize("fixture", sorted(FIXTURES))
+def test_recorded_stage_readings_are_unchanged(fixture):
+    assert stages.split(_recorded(FIXTURES[fixture])) == RECORDED_SPLIT[fixture]
+
+
+def _outermost_ms(ctx, scope, edge=False):
+    """By brute force: the time of chip 0's events of `scope` that lie in
+    no other event of it; with `edge`, only the body events among them that
+    lie in no top-level event either."""
+    ops = ctx["trace"].devices[0]
+    named = [o for o in ops if scope in stages._components(
+        ctx["instrs"].get(o.name, {}).get("op_name", ""))]
+    around = named + ([o for o in ops if stages.top_level(ctx, o.name)]
+                      if edge else [])
+
+    def inside(o, p):
+        return (p is not o and p.start <= o.start and o.end <= p.end
+                and (p.start, -p.end) < (o.start, -o.end))
+
+    return sum(o.end - o.start for o in named
+               if not any(inside(o, p) for p in around)
+               and not (edge and stages.top_level(ctx, o.name))) * 1e3
+
+
+@pytest.mark.parametrize("fixture", sorted(FIXTURES))
+@pytest.mark.parametrize("scope", ["checkpoint", "transpose(jvp())",
+                                   "rematted_computation", "fwd_bwd"])
+def test_recorded_scope_counts_each_event_once(fixture, scope):
+    ctx = _recorded(FIXTURES[fixture])
+    value = stages.scope_ms(ctx, scope)
+    assert value == pytest.approx(_outermost_ms(ctx, scope), rel=1e-9)
+    assert 0 < value
+    if scope != "fwd_bwd":
+        assert value <= _read(ctx, "fwd_bwd_ms")
+
+
+@pytest.mark.parametrize("fixture", sorted(FIXTURES))
+def test_recorded_scope_of_a_stage_reads_the_stage(fixture):
+    """The scope of a whole stage reads the stage's top-level time, and
+    more only by the loop bodies whose loop began before the recorded step:
+    the fixture holds their events, not the loop's. A traced window holds
+    the loop's event too, and counts neither."""
+    ctx = _recorded(FIXTURES[fixture])
+    for metric, stage in (("fwd_bwd_ms", "fwd_bwd"), ("wire_ms", "wire")):
+        edge = _outermost_ms(ctx, stage, edge=True)
+        assert stages.scope_ms(ctx, stage) == pytest.approx(
+            _read(ctx, metric) + edge, rel=1e-9)
+        assert edge == 0 or stage == "fwd_bwd"
+
+
+def test_recorded_dp4_collectives_are_in_their_stages():
+    """On four chips the integer all-reduce runs on the XLA Ops line inside
+    ``wire``, and ZeRO-1's all-gathers inside ``update``."""
+    ctx = _recorded(FIXTURES["granite_dp4"])
+    instrs = ctx["instrs"]
+
+    def ms_of(opcode, stage):
+        return sum(o.end - o.start for o in ctx["trace"].devices[0]
+                   if instrs.get(o.name, {}).get("opcode") == opcode
+                   and stages.top_level(ctx, o.name)
+                   and stages.stage_of(ctx, o.name) == stage) * 1e3
+
+    all_reduce, all_gather = ms_of("all-reduce", "wire"), ms_of("all-gather", "update")
+    assert 5 < all_reduce < _read(ctx, "wire_ms")
+    assert 5 < all_gather < _read(ctx, "update_ms")
