@@ -26,12 +26,28 @@ class ModelConfig:
     qkv_bias: bool = False
     window: Optional[int] = None  # sliding-window attention
     rope_theta: float = 10000.0
-    # MoE
+    # MoE. ``n_experts`` is the router's width; ``d_ff`` the width of one
+    # routed expert, and n_shared_experts * d_ff the shared SwiGLU's.
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0
+    # experts held on this chip, the first n_experts_held of the router's
+    # (one chip's share under expert parallelism); 0: all of them
+    n_experts_held: int = 0
+    norm_topk_prob: bool = True  # renormalise the top-k gate weights
+    aux_loss_alpha: float = 0.0  # sequence-wise balance loss coefficient
+    # leading dense layers before the MoE stack, and their SwiGLU width
+    first_k_dense: int = 0
+    d_ff_dense: int = 0
     # MLA
     kv_lora: int = 0
+    # YaRN rotary scaling (0: none), as DeepSeek-V2's rope_scaling gives it
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # hybrid / ssm
     ssm_state: int = 0
     attn_every: int = 0
@@ -142,6 +158,9 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         n_experts=4 if cfg.n_experts else 0,
         top_k=min(cfg.top_k, 2) if cfg.top_k else 0,
         n_shared_experts=min(cfg.n_shared_experts, 1),
+        n_experts_held=min(cfg.n_experts_held, 2),
+        first_k_dense=min(cfg.first_k_dense, 1),
+        d_ff_dense=128 if cfg.d_ff_dense else 0,
         ssm_state=16 if cfg.ssm_state else 0,
         attn_every=2 if cfg.attn_every else 0,
         enc_layers=2 if cfg.enc_layers else 0,

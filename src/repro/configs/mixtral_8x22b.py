@@ -14,6 +14,7 @@ CONFIG = ModelConfig(
     head_dim=128,
     n_experts=8,
     top_k=2,
+    norm_topk_prob=True,
     window=4096,
     source="arXiv:2401.04088",
 )

@@ -18,13 +18,27 @@ and a device trace can put each operation's time down to a stage:
             route with its pads and reshapes
   counters  the wire-width statistics the step returns (max |int|, bits)
 
+Stages never nest in one another. Inside ``fwd_bwd`` the model names its
+own mechanisms with the scopes of ``MODEL_SCOPES``, so that a trace can
+read one mechanism's time out of the forward and backward pass:
+
+  mla          multi-head latent attention (``models/mla.py::mla_train``)
+  moe_route    the router's gate, top-k and balance loss, and the grouping
+               of the routed rows and their scatter back
+               (``models/moe.py``)
+  moe_experts  the held experts' grouped products over their routed rows
+
+A model scope is entered inside the differentiated function, so its
+backward operations carry it too
+(``fwd_bwd/transpose(jvp(checkpoint))/.../moe_experts/dot_general``).
+
 Scopes are compile-time metadata: they change no instruction of the
-compiled program, only its names, so there is no switch. Stages never
-nest. A call site enters one with ``stages.stage(name)``, or
-``@stages.scoped(name)`` around a whole function, always through this
-module, so that a test can replace ``stage``. Compressors outside the IntSGD
-family keep their ``aggregate`` unscoped, apart from the ``wire`` that
-``CommCtx.psum_wire`` gives them.
+compiled program, only its names, so there is no switch. A call site
+enters a stage with ``stages.stage(name)``, or ``@stages.scoped(name)``
+around a whole function, and a model scope with ``stages.scope(name)``,
+always through this module, so that a test can replace them. Compressors
+outside the IntSGD family keep their ``aggregate`` unscoped, apart from the
+``wire`` that ``CommCtx.psum_wire`` gives them.
 """
 from __future__ import annotations
 
@@ -34,6 +48,7 @@ import jax
 
 STAGES = ("fwd_bwd", "alpha", "encode", "wire", "decode", "clip",
           "update", "counters")
+MODEL_SCOPES = ("mla", "moe_route", "moe_experts")
 
 
 def stage(name: str):
@@ -57,3 +72,13 @@ def scoped(name: str):
         return inner
 
     return wrap
+
+
+def scope(name: str):
+    """A ``jax.named_scope`` for one of ``MODEL_SCOPES``, which may nest
+    inside the stage ``fwd_bwd``."""
+    if name not in MODEL_SCOPES:
+        raise ValueError(
+            f"unknown model scope {name!r}; scopes are {MODEL_SCOPES}")
+    return jax.named_scope(name)
+

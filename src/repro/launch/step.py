@@ -66,7 +66,8 @@ from repro.models.encdec import (
     encdec_loss,
     encode as encdec_encode,
 )
-from repro.models.transformer import lm_forward, lm_logits_local, lm_loss
+from repro.models import moe as moe_mod
+from repro.models.transformer import lm_forward, lm_logits_local, lm_loss_stats
 from repro.optim import base as optb
 from repro.optim.base import Optimizer
 from repro.optim.zero1 import (
@@ -165,7 +166,27 @@ def _comp_state_shapes(comp: Compressor, cfg, tp, n_dp):
 
 
 def _loss_fn_for(cfg: ModelConfig):
-    return encdec_loss if cfg.family == "encdec" else lm_loss
+    """loss_fn(params, batch, axes, cfg, dtype=...) -> (loss, stats)."""
+    if cfg.family == "encdec":
+        return lambda *a, **kw: (encdec_loss(*a, **kw), {})
+    return lm_loss_stats
+
+
+def _counts_expert_rows(cfg: ModelConfig, tp: int) -> bool:
+    """Whether the step returns the held experts' routed rows beside
+    (max_int, bits): an MoE model on its held-experts layer."""
+    return (cfg.family == "moe"
+            and moe_mod.pick_strategy(cfg.n_experts, tp) == "held")
+
+
+def _row_counters(layout: "Layout", rows):
+    """(largest, mean) of the rows routed to a held expert in one step,
+    over layers and held experts (rows: (MoE layers, held)); the largest
+    over workers and the mean of their means."""
+    with stages.stage("counters"):
+        rows = rows.astype(jnp.float32)
+        return (coll.pmax(jnp.max(rows), layout.dp),
+                coll.psum(jnp.mean(rows), layout.dp) / layout.n_dp)
 
 
 def _fused_state_struct(base_opt: Optimizer, shapes):
@@ -290,12 +311,18 @@ def _sharded(layout: Layout, body, in_specs, out_specs, *, donate=(),
 # ---------------------------------------------------------------------------
 @stages.scoped("fwd_bwd")
 def _forward_backward(layout: Layout, loss_fn, params, batch):
-    loss, grads = jax.value_and_grad(
-        lambda p: loss_fn(p, batch, layout.axes, layout.cfg, dtype=jnp.bfloat16)
+    """(loss, grads, stats): the loss function's stats ride out beside."""
+    (loss, stats), grads = jax.value_and_grad(
+        lambda p: loss_fn(p, batch, layout.axes, layout.cfg, dtype=jnp.bfloat16),
+        has_aux=True,
     )(params)
     if layout.tp > 1:
         grads = _fix_replicated_grads(grads, layout.rep_mask, "model")
-    return loss, grads
+    return loss, grads, stats
+
+
+def _add_stats(acc, stats):
+    return stats if acc is None else jax.tree.map(jnp.add, acc, stats)
 
 
 def _unstack_comp(comp_state):
@@ -432,10 +459,11 @@ def _pipelined_grad_stage(
     wf = compressor.wire_format
     loss_acc = jnp.zeros(())
     max_int = jnp.zeros(())
-    int_acc = local_acc = alphas = None
+    int_acc = local_acc = alphas = stats = None
     for m in range(n_micro):
         mb = _microbatch(batch, m, n_micro)
-        loss_m, grads_m = _forward_backward(layout, loss_fn, params, mb)
+        loss_m, grads_m, stats_m = _forward_backward(layout, loss_fn, params, mb)
+        stats = _add_stats(stats, stats_m)
         ints_m, alphas = compressor.encode_ints(
             cs, grads_m, key=jax.random.fold_in(akey, m), eta=eta,
             ctx=layout.ctx, dims=layout.dims, n_accum=n_micro,
@@ -462,24 +490,25 @@ def _pipelined_grad_stage(
     )
     with stages.stage("counters"):
         bits = 1.0 + jnp.ceil(jnp.log2(jnp.maximum(max_int, 1.0) + 1.0))
-    return ghat, cs, loss_acc / n_micro, (max_int, bits)
+    return ghat, cs, loss_acc / n_micro, (max_int, bits), stats
 
 
 def _accum_grad_stage(layout: Layout, loss_fn, params, batch, n_micro: int):
     """Plain gradient accumulation (exact step / non-IntSGD compressors):
     mean of the microbatch gradients in f32, one aggregation afterwards."""
     loss_acc = jnp.zeros(())
-    g_acc = None
+    g_acc = stats = None
     for m in range(n_micro):
         mb = _microbatch(batch, m, n_micro)
-        loss_m, grads_m = _forward_backward(layout, loss_fn, params, mb)
+        loss_m, grads_m, stats_m = _forward_backward(layout, loss_fn, params, mb)
+        stats = _add_stats(stats, stats_m)
         with stages.stage("fwd_bwd"):
             g32 = jax.tree.map(lambda g: g.astype(jnp.float32), grads_m)
             g_acc = g32 if g_acc is None else jax.tree.map(jnp.add, g_acc, g32)
         loss_acc = loss_acc + loss_m
     with stages.stage("fwd_bwd"):
         grads = jax.tree.map(lambda g: g / n_micro, g_acc)
-    return loss_acc / n_micro, grads
+    return loss_acc / n_micro, grads, stats
 
 
 def _make_train_body(
@@ -513,7 +542,7 @@ def _make_train_body(
         akey = jax.random.fold_in(key, 1)
         m_axes = layout.dp + (("model",) if layout.tp > 1 else ())
         if not exact and pipelined:
-            ghat, cs, loss, (max_int, bits) = _pipelined_grad_stage(
+            ghat, cs, loss, (max_int, bits), stats = _pipelined_grad_stage(
                 layout, loss_fn, compressor, cs, params, batch, akey, eta,
                 microbatches,
             )
@@ -521,11 +550,12 @@ def _make_train_body(
                 metrics = (coll.pmax(max_int, m_axes), coll.pmax(bits, m_axes))
         else:
             if microbatches > 1:
-                loss, grads = _accum_grad_stage(
+                loss, grads, stats = _accum_grad_stage(
                     layout, loss_fn, params, batch, microbatches
                 )
             else:
-                loss, grads = _forward_backward(layout, loss_fn, params, batch)
+                loss, grads, stats = _forward_backward(
+                    layout, loss_fn, params, batch)
             if exact:
                 ghat = aggregate_exact(grads, layout.ctx)
                 metrics = (jnp.zeros(()), jnp.zeros(()))
@@ -587,6 +617,8 @@ def _make_train_body(
         cs = _observe_dx(layout, compressor, base_opt, cs, new_params, params)
         new_comp = _restack_comp(cs, comp_state)
         loss_g = coll.psum(loss, layout.dp) / layout.n_dp
+        if "expert_rows" in stats:
+            metrics = metrics + _row_counters(layout, stats["expert_rows"])
         return new_params, new_opt, new_comp, loss_g, metrics
 
     return step
@@ -737,7 +769,10 @@ def build_train_step(
         P(),
         batch_specs,
     )
-    out_specs = (layout.pspecs, opt_specs, comp_specs, P(), (P(), P()))
+    # metrics: (max_int, bits), and an MoE model's held experts' routed
+    # rows, (largest, mean), beside them
+    n_metrics = 4 if _counts_expert_rows(cfg, layout.tp) else 2
+    out_specs = (layout.pspecs, opt_specs, comp_specs, P(), (P(),) * n_metrics)
 
     def make(exact):
         body = _make_train_body(
@@ -887,7 +922,8 @@ def build_eval_step(
     batch_specs = specs_mod.batch_pspecs(batch_struct, dp=layout.dp)
 
     def body(params, batch):
-        loss = loss_fn(params, batch, layout.axes, layout.cfg, dtype=jnp.bfloat16)
+        loss, _ = loss_fn(params, batch, layout.axes, layout.cfg,
+                          dtype=jnp.bfloat16)
         return coll.psum(loss, layout.dp) / layout.n_dp
 
     in_specs = (layout.pspecs, batch_specs)

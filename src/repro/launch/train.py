@@ -156,10 +156,15 @@ def train_loop(
             now = time.perf_counter()
             dt, last_read = now - last_read, now
             if i % log_every == 0 or i == steps - 1:
+                # an MoE model's held experts: rows routed to the busiest
+                # and to the average one, over layers
+                rows = (f"expert_rows max {float(metrics[2]):.0f} "
+                        f"mean {float(metrics[3]):.1f} "
+                        if len(metrics) > 2 else "")
                 print(
                     f"[train] step {i:5d} loss {losses[-1]:.4f} "
                     f"max_int {float(metrics[0]):.0f} bits {float(metrics[1]):.0f} "
-                    f"dt {dt * 1e3:.1f}ms"
+                    f"{rows}dt {dt * 1e3:.1f}ms"
                 )
             if ckpt and (i + 1) % ckpt_every == 0:
                 with jax.profiler.TraceAnnotation("checkpoint"):

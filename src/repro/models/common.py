@@ -137,13 +137,18 @@ def layernorm(x, w, b, eps=1e-5):
 # --------------------------------------------------------------------------
 # rotary embeddings
 # --------------------------------------------------------------------------
-def rope(x, positions, theta: float = 10000.0):
-    """x: (..., T, H, dh); positions: (..., T) int32."""
+def rope(x, positions, theta: float = 10000.0, inv_freq=None):
+    """x: (..., T, H, dh); positions: (..., T) int32. The two halves of
+    each head rotate together, at `inv_freq` (dh/2 of them) where given,
+    else at theta^(-j/(dh/2))."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = jnp.exp(
-        -jnp.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
-    )
+    if inv_freq is not None:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
+    else:
+        freqs = jnp.exp(
+            -jnp.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
+        )
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., T, half)
     cos = jnp.cos(ang)[..., None, :]  # (..., T, 1, half)
     sin = jnp.sin(ang)[..., None, :]

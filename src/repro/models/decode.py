@@ -50,7 +50,10 @@ def init_lm_cache(cfg, tp: int, n_shards: int, b_local: int, s_local: int, dtype
             base = mla_mod.init_mla_cache(b_local, s_local, cfg.kv_lora, dtype)
         else:
             base = attn.init_cache(b_local, s_local, dims.layout, dtype)
-        return {"layers": stack(base, L)}
+        out = {"layers": stack(base, L - cfg.first_k_dense)}
+        if cfg.first_k_dense:
+            out["dense_layers"] = stack(base, cfg.first_k_dense)
+        return out
     if cfg.family == "hybrid":
         nb = cfg.n_layers // cfg.attn_every
         m = ssm_mod.init_mamba2_cache(
@@ -77,6 +80,7 @@ def _attn_decode_any(lp, h, pos, lc, axes, cfg, dims):
         return mla_mod.mla_decode(
             lp, h, pos, lc, axes,
             n_heads_local=dims.layout.q_local, head_dim=dims.layout.head_dim,
+            rot=mla_mod.rope_of(cfg),
         )
     return attn.attention_decode(
         lp, h, pos, lc, axes, dims.layout,
@@ -93,23 +97,27 @@ def lm_decode_step(params, cache, tokens, pos, axes: Axes, cfg, dtype=jnp.bfloat
 
     if cfg.family in ("dense", "vlm", "moe"):
 
-        def body(h, xs):
+        def body(h, xs, moe):
             lp, lc = xs
             a, new_lc = _attn_decode_any(
                 lp["attn"], rmsnorm(h, lp["ln1"]), pos, lc, axes, cfg, dims
             )
             h = h + a
             z = rmsnorm(h, lp["ln2"])
-            if cfg.family == "moe":
-                h = h + moe_mod.moe_block(
-                    lp["moe"], z, axes, n_experts=cfg.n_experts, top_k=cfg.top_k
-                )
+            if moe:
+                h = h + moe_mod.moe_block(lp["moe"], z, axes, cfg)[0]
             else:
                 h = h + swiglu_mlp(lp["mlp"], z, axes)
             return h, new_lc
 
-        x, new_layers = lax.scan(body, x, (params["layers"], cache["layers"]))
-        new_cache = {"layers": new_layers}
+        new_cache = {}
+        if "dense_layers" in params:  # an MoE model's leading dense layers
+            x, new_cache["dense_layers"] = lax.scan(
+                partial(body, moe=False), x,
+                (params["dense_layers"], cache["dense_layers"]))
+        x, new_cache["layers"] = lax.scan(
+            partial(body, moe=cfg.family == "moe"), x,
+            (params["layers"], cache["layers"]))
     elif cfg.family == "hybrid":
         emb0 = x
 

@@ -46,6 +46,7 @@ class Dims:
     e_loc: int = 0
     ff_e_loc: int = 0
     ff_shared_loc: int = 0
+    ff_dense_loc: int = 0  # the leading dense layers' SwiGLU
     # ssm
     ssm_heads_loc: int = 0
     ssm_head_dim: int = 64
@@ -69,7 +70,10 @@ def resolve_dims(cfg, tp: int, n_shards: int) -> Dims:
     kw = {}
     if cfg.n_experts:
         strategy = moe_mod.pick_strategy(cfg.n_experts, tp)
-        if strategy == "ep":
+        if strategy == "held":
+            kw["e_loc"] = cfg.n_experts_held or cfg.n_experts
+            kw["ff_e_loc"] = cfg.d_ff
+        elif strategy == "ep":
             kw["e_loc"] = cfg.n_experts // n_shards
             kw["ff_e_loc"] = cfg.d_ff
         else:
@@ -78,6 +82,8 @@ def resolve_dims(cfg, tp: int, n_shards: int) -> Dims:
         if cfg.n_shared_experts:
             ff_sh = pad_to_multiple(cfg.d_ff * cfg.n_shared_experts, tp)
             kw["ff_shared_loc"] = ff_sh // n_shards
+        if cfg.first_k_dense:
+            kw["ff_dense_loc"] = pad_to_multiple(cfg.d_ff_dense, tp) // n_shards
     if cfg.ssm_state:
         d_inner = 2 * cfg.d_model
         heads = d_inner // 64
@@ -97,12 +103,12 @@ def resolve_dims(cfg, tp: int, n_shards: int) -> Dims:
 # ---------------------------------------------------------------------------
 # per-layer init/apply
 # ---------------------------------------------------------------------------
-def _init_dense_layer(key, cfg, dims: Dims, dtype):
+def _init_dense_layer(key, cfg, dims: Dims, dtype, d_ff_loc=None):
     ks = jax.random.split(key, 2)
     p = {
         "ln1": jnp.ones((cfg.d_model,), dtype),
         "ln2": jnp.ones((cfg.d_model,), dtype),
-        "mlp": init_swiglu(ks[1], cfg.d_model, dims.d_ff_loc, dtype),
+        "mlp": init_swiglu(ks[1], cfg.d_model, d_ff_loc or dims.d_ff_loc, dtype),
     }
     if cfg.kv_lora:
         p["attn"] = mla_mod.init_mla_params(
@@ -116,26 +122,29 @@ def _init_dense_layer(key, cfg, dims: Dims, dtype):
 
 
 def _init_moe_layer(key, cfg, dims: Dims, dtype):
-    ks = jax.random.split(key, 3)
+    """One key per tensor: attention, router, the experts' gate, up and
+    down projections, the shared experts."""
+    k_attn, k_router, k_gate, k_up, k_down, k_shared = jax.random.split(key, 6)
+    d, e, f = cfg.d_model, dims.e_loc, dims.ff_e_loc
     p = {
-        "ln1": jnp.ones((cfg.d_model,), dtype),
-        "ln2": jnp.ones((cfg.d_model,), dtype),
+        "ln1": jnp.ones((d,), dtype),
+        "ln2": jnp.ones((d,), dtype),
         "moe": {
-            "router": dense_init(ks[1], (cfg.d_model, cfg.n_experts), cfg.d_model, jnp.float32),
-            "w_gate": dense_init(ks[2], (dims.e_loc, cfg.d_model, dims.ff_e_loc), cfg.d_model, dtype),
-            "w_up": dense_init(ks[2], (dims.e_loc, cfg.d_model, dims.ff_e_loc), cfg.d_model, dtype),
-            "w_down": dense_init(ks[2], (dims.e_loc, dims.ff_e_loc, cfg.d_model), dims.ff_e_loc, dtype),
+            "router": dense_init(k_router, (d, cfg.n_experts), d, jnp.float32),
+            "w_gate": dense_init(k_gate, (e, d, f), d, dtype),
+            "w_up": dense_init(k_up, (e, d, f), d, dtype),
+            "w_down": dense_init(k_down, (e, f, d), f, dtype),
         },
     }
     if cfg.n_shared_experts:
-        p["moe"]["shared"] = init_swiglu(ks[0], cfg.d_model, dims.ff_shared_loc, dtype)
+        p["moe"]["shared"] = init_swiglu(k_shared, d, dims.ff_shared_loc, dtype)
     if cfg.kv_lora:
         p["attn"] = mla_mod.init_mla_params(
-            ks[0], cfg.d_model, dims.layout.q_local, dims.layout.head_dim, cfg.kv_lora, dtype
+            k_attn, d, dims.layout.q_local, dims.layout.head_dim, cfg.kv_lora, dtype
         )
     else:
         p["attn"] = attn.init_attn_params(
-            ks[0], cfg.d_model, dims.layout, bias=cfg.qkv_bias, dtype=dtype
+            k_attn, d, dims.layout, bias=cfg.qkv_bias, dtype=dtype
         )
     return p
 
@@ -145,6 +154,7 @@ def _apply_attn_train(p, x, positions, axes, cfg, dims):
         return mla_mod.mla_train(
             p, x, positions, axes,
             n_heads_local=dims.layout.q_local, head_dim=dims.layout.head_dim,
+            rot=mla_mod.rope_of(cfg),
         )
     return attn.attention_train(
         p, x, positions, axes, dims.layout,
@@ -159,12 +169,10 @@ def _dense_layer(p, x, positions, axes, cfg, dims):
 
 
 def _moe_layer(p, x, positions, axes, cfg, dims):
+    """Returns (h, (aux loss, rows per held expert or None))."""
     h = x + _apply_attn_train(p["attn"], rmsnorm(x, p["ln1"]), positions, axes, cfg, dims)
-    h = h + moe_mod.moe_block(
-        p["moe"], rmsnorm(h, p["ln2"]), axes,
-        n_experts=cfg.n_experts, top_k=cfg.top_k,
-    )
-    return h
+    out, aux, rows = moe_mod.moe_block(p["moe"], rmsnorm(h, p["ln2"]), axes, cfg)
+    return h + out, (aux, rows)
 
 
 # ---- zamba2-style hybrid: mamba backbone + shared attention block ----------
@@ -249,8 +257,13 @@ def init_lm_params(key, cfg, tp: int = 1, n_shards: int = 1, dtype=jnp.float32):
             lambda k: _init_dense_layer(k, cfg, dims, dtype)
         )(lk)
     elif cfg.family == "moe":
-        lk = jax.random.split(keys[2], cfg.n_layers)
+        # the leading dense layers, then the MoE stack
+        lk = jax.random.split(keys[2], cfg.n_layers - cfg.first_k_dense)
         params["layers"] = jax.vmap(lambda k: _init_moe_layer(k, cfg, dims, dtype))(lk)
+        if cfg.first_k_dense:
+            dk = jax.random.split(keys[3], cfg.first_k_dense)
+            params["dense_layers"] = jax.vmap(lambda k: _init_dense_layer(
+                k, cfg, dims, dtype, dims.ff_dense_loc))(dk)
     elif cfg.family == "hybrid":
         nb = cfg.n_layers // cfg.attn_every
         lk = jax.random.split(keys[2], cfg.n_layers)
@@ -299,25 +312,48 @@ def _embed_inputs(params, batch, axes, cfg):
     return x, positions
 
 
+def _scan_layers(layer_fn, x, stacked, positions, axes, cfg, dims):
+    """x through the stacked layers, each rematerialised in the backward
+    pass; layer_fn(p, h, ...) -> (h, per-layer outputs)."""
+    ckpt = _remat(cfg)
+
+    def body(h, lp):
+        return ckpt(
+            lambda hh, pp: layer_fn(pp, hh, positions, axes, cfg, dims)
+        )(h, lp)
+
+    return lax.scan(body, x, stacked)
+
+
 def lm_forward(params, batch, axes: Axes, cfg, dtype=jnp.bfloat16):
     """Returns hidden states after final norm: (B, T', d)."""
+    return lm_forward_stats(params, batch, axes, cfg, dtype)[0]
+
+
+def lm_forward_stats(params, batch, axes: Axes, cfg, dtype=jnp.bfloat16):
+    """(hidden states after the final norm (B, T', d), stats): for an MoE
+    model the balance loss summed over layers (``aux``) and, for the held
+    experts' layer, the rows routed to each held expert (``expert_rows``,
+    (MoE layers, held) int32); else {}."""
     tp = axes.tp_size
     dims = resolve_dims(cfg, tp, tp)
     x, positions = _embed_inputs(params, batch, axes, cfg)
     x = x.astype(dtype)
+    stats = {}
 
-    if cfg.family in ("dense", "vlm", "moe"):
-        layer_fn = _dense_layer if cfg.family != "moe" else _moe_layer
-
-        ckpt = _remat(cfg)
-
-        def body(h, lp):
-            h = ckpt(
-                lambda hh, pp: layer_fn(pp, hh, positions, axes, cfg, dims)
-            )(h, lp)
-            return h, None
-
-        x, _ = lax.scan(body, x, params["layers"])
+    if cfg.family in ("dense", "vlm"):
+        dense = lambda *a: (_dense_layer(*a), None)
+        x, _ = _scan_layers(dense, x, params["layers"], positions, axes, cfg, dims)
+    elif cfg.family == "moe":
+        if cfg.first_k_dense:
+            dense = lambda *a: (_dense_layer(*a), None)
+            x, _ = _scan_layers(dense, x, params["dense_layers"], positions,
+                                axes, cfg, dims)
+        x, (aux, rows) = _scan_layers(_moe_layer, x, params["layers"],
+                                      positions, axes, cfg, dims)
+        stats["aux"] = jnp.sum(aux)
+        if rows is not None:
+            stats["expert_rows"] = rows
     elif cfg.family == "hybrid":
         emb0 = x
 
@@ -343,7 +379,7 @@ def lm_forward(params, batch, axes: Axes, cfg, dtype=jnp.bfloat16):
             return _xlstm_block(bp, h, axes, cfg, dims), None
 
         x, _ = lax.scan(body, x, params["layers"])
-    return rmsnorm(x, params["ln_f"])
+    return rmsnorm(x, params["ln_f"]), stats
 
 
 def lm_logits_local(params, h, cfg):
@@ -352,11 +388,18 @@ def lm_logits_local(params, h, cfg):
 
 
 def lm_loss(params, batch, axes: Axes, cfg, dtype=jnp.bfloat16):
-    h = lm_forward(params, batch, axes, cfg, dtype)
+    return lm_loss_stats(params, batch, axes, cfg, dtype)[0]
+
+
+def lm_loss_stats(params, batch, axes: Axes, cfg, dtype=jnp.bfloat16):
+    """(mean next-token cross entropy plus an MoE model's balance loss,
+    the stats of ``lm_forward_stats``)."""
+    h, stats = lm_forward_stats(params, batch, axes, cfg, dtype)
     if cfg.frontend == "vit":  # only text positions carry labels
         h = h[:, -batch["tokens"].shape[1] :]
     logits = lm_logits_local(params, h, cfg)
     labels = batch["labels"]
     per_tok = tp_cross_entropy(logits, labels, axes)
     mask = (labels >= 0).astype(jnp.float32)
-    return jnp.sum(per_tok * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    loss = jnp.sum(per_tok * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    return loss + stats.pop("aux", 0.0), stats

@@ -16,13 +16,15 @@ Step protocol (inside shard_map):
     3. the new bf16 shard is all-gathered over dp → full new params.
 No step ever forms the (n_dp, k/n_dp) view of a full leaf: the TPU compiler
 spends minutes on such a view of a large leaf, and seconds on a 1-D slice
-and a tiled gather.
+and a tiled gather. For the same reason a leaf of stacked layers is
+flattened from its 2-D view (``_flat``).
 The all-gather is bf16 (half the bytes of the f32 gradient it replaces in a
 ZeRO-less design) and is the only extra collective ZeRO-1 introduces.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Tuple
 
 import jax
@@ -34,6 +36,17 @@ from repro.optim.base import Optimizer
 from repro.parallel import collectives as coll
 
 
+def _flat(x):
+    """x in one row, in row-major order. A leaf of stacked layers goes
+    through its 2-D view (every axis but the last merged, a relabelling of
+    the same tiles) behind an optimization barrier: the TPU compiler takes
+    minutes to relayout a stacked 3-D or 4-D leaf straight into a row (50
+    to 170 s a leaf at DeepSeek-V2-Lite's widths), and a second from 2-D."""
+    if x.ndim > 2 and math.prod(x.shape[:-2]) > 1:
+        x = lax.optimization_barrier(x.reshape(-1, x.shape[-1]))
+    return x.reshape(-1)
+
+
 def _pad_rows(flat, n_dp):
     k = flat.shape[0]
     per = (k + n_dp - 1) // n_dp
@@ -43,12 +56,12 @@ def _pad_rows(flat, n_dp):
 def shard_leaf(x, n_dp):
     """param leaf -> (n_dp, k/n_dp) f32 master layout (shapes only: the
     state builders take one row each with :func:`own_row`)."""
-    return _pad_rows(x.reshape(-1).astype(jnp.float32), n_dp)
+    return _pad_rows(_flat(x).astype(jnp.float32), n_dp)
 
 
 def own_row(x, n_dp, dp_index):
     """This dp member's (k/n_dp,) f32 row of the master layout of leaf x."""
-    flat = x.reshape(-1).astype(jnp.float32)
+    flat = _flat(x).astype(jnp.float32)
     per = -(-flat.size // n_dp)
     flat = jnp.pad(flat, (0, per * n_dp - flat.size))
     return lax.dynamic_slice_in_dim(flat, dp_index * per, per)

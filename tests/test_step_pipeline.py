@@ -182,3 +182,21 @@ def test_fused_route_capability_errors(mesh):
                 cfg, mesh, shape, compressor=make_compressor(comp),
                 base_opt=opt, lr_schedule=constant(0.1), fused=True,
             )
+
+
+@pytest.mark.parametrize("shape", [(6,), (3, 4), (1, 3, 4), (2, 3, 4), (2, 2, 3, 4)])
+@pytest.mark.parametrize("n_dp", [1, 3])
+def test_zero1_rows_are_the_leaf_in_row_major_order(shape, n_dp):
+    """ZeRO-1's master layout holds every leaf, stacked layers too (they go
+    through their 2-D view), as its row-major elements, padded with zeros,
+    split into n_dp rows; own_row gives each member its row."""
+    from repro.optim.zero1 import own_row, shard_leaf
+
+    x = jnp.arange(np.prod(shape), dtype=jnp.float32).reshape(shape) + 1.0
+    per = -(-x.size // n_dp)
+    want = np.pad(np.arange(x.size, dtype=np.float32) + 1.0,
+                  (0, per * n_dp - x.size)).reshape(n_dp, per)
+    np.testing.assert_array_equal(shard_leaf(x, n_dp), want)
+    for i in range(n_dp):
+        np.testing.assert_array_equal(jax.jit(own_row, static_argnums=1)(
+            x, n_dp, jnp.int32(i)), want[i])

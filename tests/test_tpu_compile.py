@@ -11,6 +11,7 @@ The topology is described inside a fixture, so only the test worker that
 runs this file loads the TPU library. ``jax.default_backend()`` still says
 CPU here, so the tests steer the kernels out of interpret mode themselves.
 """
+import base64
 import os
 import re
 
@@ -186,3 +187,47 @@ def test_fused_train_step_compiles_for_v5e(topo, kernels_compiled, opt):
     )
     compiled = art.jitted["compressed"].lower(*art.arg_structs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_held_experts_compile_for_v5e(one_chip, kernels_compiled):
+    """The held experts' grouped SwiGLU, forward and backward, over one
+    pass of the layer's loop at DeepSeek-V2-Lite widths: 8,192 grouped rows
+    of d_model 2048 through 8 experts of width 1408 (megablox's gmm and
+    tgmm, tiled by ``moe._tiling``)."""
+    from repro.models import moe
+
+    def loss(xs, sizes, wg, wu, wd):
+        return jnp.sum(moe._grouped_swiglu(xs, sizes, wg, wu, wd)
+                       .astype(jnp.float32))
+
+    grad = jax.grad(loss, argnums=(0, 2, 3, 4))
+    args = [((moe.CHUNK, 2048), jnp.bfloat16), ((8,), jnp.int32),
+            ((8, 2048, 1408), jnp.float32), ((8, 2048, 1408), jnp.float32),
+            ((8, 1408, 2048), jnp.float32)]
+    text = jax.jit(grad).lower(
+        *[_sds(one_chip, s, dt) for s, dt in args]).compile().as_text()
+    # three products forward; their data and weight gradients backward
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6
+
+
+def test_latent_attention_flash_compiles_for_v5e(one_chip, kernels_compiled):
+    """MLA's causal attention, forward and backward, at DeepSeek-V2-Lite
+    widths over 8,192 positions: 16 heads, q and k 192 wide and v 128,
+    padded to 256 for the flash kernel, in blocks of 512 rows."""
+    from repro.models import mla
+
+    def loss(q, k, v):
+        pos = jnp.broadcast_to(jnp.arange(8192, dtype=jnp.int32), (1, 8192))
+        out = mla._attend(q, k, v, pos, 1.59)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qk, vv = ((1, 8192, 16, 192), jnp.bfloat16), ((1, 8192, 16, 128), jnp.bfloat16)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *[_sds(one_chip, s, dt) for s, dt in (qk, qk, vv)]).compile().as_text()
+    # the Mosaic modules the custom calls carry name their kernels: the
+    # forward (run again for the backward's residuals), dk/dv and dq
+    bodies = b"".join(base64.b64decode(b) for b in
+                      re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text))
+    for kernel in (b"_flash_attention_kernel", b"_flash_attention_dkv_kernel",
+                   b"_flash_attention_dq_kernel"):
+        assert kernel in bodies, kernel
