@@ -13,8 +13,10 @@ style, replication checks disabled):
     words, selected via the compressor's ``wire`` field or the ``wire=``
     argument here — see repro.wire), the paper's no-floats contract;
   * optimizer update, routed one of two ways:
-      - "zero1": ZeRO-1 update on dp-sharded f32 masters, bf16 param
-        all-gather (the default);
+      - "zero1": ZeRO-1 update on dp-sharded f32 masters, each leaf's
+        share a block of its own rows where its shape allows
+        (optim.zero1.zero1_view), then the parameters' all-gather (the
+        default);
       - "fused": the Pallas decode+update kernel family — integer
         dequantization folded into the optimizer step (momentum-SGD or
         bias-corrected AdamW, plus the IntDIANA global-shift add/advance),
@@ -71,7 +73,7 @@ from repro.models.transformer import lm_forward, lm_logits_local, lm_loss_stats
 from repro.optim import base as optb
 from repro.optim.base import Optimizer
 from repro.optim.zero1 import (
-    own_row,
+    own_share,
     zero1_init,
     zero1_state_specs,
     zero1_update,
@@ -135,7 +137,8 @@ class StepArtifacts:
 def _zero1_shapes_global(local_state, tp):
     def up(l):
         if l.ndim >= 2:
-            return jax.ShapeDtypeStruct((l.shape[0], l.shape[1] * tp), l.dtype)
+            return jax.ShapeDtypeStruct(
+                (l.shape[0], l.shape[1] * tp) + l.shape[2:], l.dtype)
         return l
 
     return jax.tree.map(up, local_state)
@@ -611,6 +614,7 @@ def _make_train_body(
                 dp_axes=layout.dp,
                 dp_index=layout.ctx.worker_index(),
                 n_dp=layout.n_dp,
+                tp=layout.tp,
                 param_dtype=param_dtype,
                 params_like=params,
             )
@@ -743,7 +747,8 @@ def build_train_step(
         opt_specs = _fused_state_specs(base_opt, layout.pspecs)
     else:
         opt_local = jax.eval_shape(
-            partial(zero1_init, base_opt, n_dp=layout.n_dp), layout.l_shapes
+            partial(zero1_init, base_opt, n_dp=layout.n_dp, tp=layout.tp),
+            layout.l_shapes,
         )
         opt_global = _zero1_shapes_global(opt_local, layout.tp)
         opt_specs = zero1_state_specs(
@@ -874,7 +879,8 @@ def build_init_state(
             layout.l_shapes,
         )
         opt_local = jax.eval_shape(
-            partial(zero1_init, base_opt, n_dp=layout.n_dp), l_params_f32
+            partial(zero1_init, base_opt, n_dp=layout.n_dp, tp=layout.tp),
+            l_params_f32,
         )
         opt_specs = zero1_state_specs(
             opt_local, layout.dp_spec, model_axis=layout.model_axis
@@ -883,7 +889,8 @@ def build_init_state(
         def init_fn(params):
             dp_index = layout.ctx.worker_index()
             my = jax.tree.map(
-                lambda p: own_row(p, layout.n_dp, dp_index)[None], params
+                lambda p: own_share(p, layout.n_dp, dp_index, layout.tp)[None],
+                params,
             )
             base_state = base_opt.init(jax.tree.map(lambda m: m[0], my))
             restack = lambda t: jax.tree.map(

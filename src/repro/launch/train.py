@@ -36,6 +36,7 @@ from repro.models.transformer import init_lm_params
 from repro.optim import adamw, sgd
 from repro.optim.base import FUSED_STATE_TENSORS
 from repro.optim.schedules import constant, warmup_wrap
+from repro.optim.zero1 import zero1_view
 from repro.parallel.collectives import mesh_from_counts
 from repro.wire import PackedInt, wire_format_names
 from repro.wire.bucketing import DEFAULT_BUCKET_WORDS
@@ -44,20 +45,36 @@ from repro.wire.bucketing import DEFAULT_BUCKET_WORDS
 PROFILE_FIRST, PROFILE_STEPS = 5, 5
 
 
+def _view_line(name, shapes, view, other: str) -> str:
+    """How many parameters of leaves of `shapes` `view` gives the "native"
+    view, and how many leaves it gives the `other` one."""
+    native = total = others = 0
+    for shape in shapes:
+        size = math.prod(shape)
+        total += size
+        if view(shape) == "native":
+            native += size
+        else:
+            others += 1
+    return (f"[train] {name} view: {native} of {total} in place, "
+            f"{others} leaves {other}")
+
+
 def fused_view_line(shapes, *, bits: int, n_tensors: int) -> str:
     """How many parameters of leaves of `shapes` the packed fused kernel
     updates in their own layout, and how many leaves it pads
     (``kernels.ops.fused_view``)."""
-    native = total = padded = 0
-    for shape in shapes:
-        size = math.prod(shape)
-        total += size
-        if fused_view(shape, bits=bits, n_tensors=n_tensors) == "native":
-            native += size
-        else:
-            padded += 1
-    return (f"[train] fused view: {native} of {total} in place, "
-            f"{padded} leaves padded")
+    return _view_line(
+        "fused", shapes,
+        lambda s: fused_view(s, bits=bits, n_tensors=n_tensors), "padded")
+
+
+def zero1_view_line(shapes, *, n_dp: int, tp: int) -> str:
+    """How many parameters of local leaves of `shapes` ZeRO-1 holds as
+    blocks of the leaf's own rows, and how many leaves it holds flat
+    (``optim.zero1.zero1_view``)."""
+    return _view_line(
+        "zero1", shapes, lambda s: zero1_view(s, n_dp, tp), "flat")
 
 
 def train_loop(
@@ -118,10 +135,12 @@ def train_loop(
             cfg, mesh, compressor=comp, base_opt=opt, fused=fused
         )
         opt_state, comp_state = init(params)
-    if fused and isinstance(comp.wire_format, PackedInt):
-        local = [sh.shard_shape(st.shape) for st, sh in zip(
-            jax.tree.leaves(art.arg_structs[0]),
-            jax.tree.leaves(art.in_shardings[0]), strict=True)]
+    local = [sh.shard_shape(st.shape) for st, sh in zip(
+        jax.tree.leaves(art.arg_structs[0]),
+        jax.tree.leaves(art.in_shardings[0]), strict=True)]
+    if not fused:
+        print(zero1_view_line(local, n_dp=n_dp, tp=tp))
+    elif isinstance(comp.wire_format, PackedInt):
         n_tensors = (1 + len(FUSED_STATE_TENSORS[opt.fused_kernel])
                      + (comp.fused_shift(comp_state) is not None))
         print(fused_view_line(

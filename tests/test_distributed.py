@@ -197,10 +197,11 @@ def pad_rows(x, n):
 
 def moment_rows(opt_state, fused, name):
     # both routes as (n_dp, k/n_dp) f32 rows: the fused route's replicated
-    # tensor resharded like the ZeRO-1 master layout
+    # tensor resharded like ZeRO-1's flat layout, a native block of ZeRO-1
+    # (its rows split over n_dp) read in row-major order
     if fused:
         return [pad_rows(l, N_DP) for l in jax.tree.leaves(opt_state[name])]
-    return [np.asarray(l, np.float32)
+    return [np.asarray(l, np.float32).reshape(N_DP, -1)
             for l in jax.tree.leaves(opt_state["base"][name])]
 
 allclose = lambda a, b: np.testing.assert_allclose(

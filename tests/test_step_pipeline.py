@@ -200,3 +200,143 @@ def test_zero1_rows_are_the_leaf_in_row_major_order(shape, n_dp):
     for i in range(n_dp):
         np.testing.assert_array_equal(jax.jit(own_row, static_argnums=1)(
             x, n_dp, jnp.int32(i)), want[i])
+
+
+@pytest.mark.parametrize("shape", [(8, 4), (2, 8, 4), (2, 2, 8, 4)])
+@pytest.mark.parametrize("n_dp", [1, 2])
+def test_zero1_native_blocks_hold_the_flat_rows(shape, n_dp):
+    """Where n_dp divides a leaf's rows, member j's block of the native
+    layout holds, in row-major order, exactly the elements of flat row j;
+    the native master layout is the leaf's 2-D view split into n_dp
+    blocks."""
+    from repro.optim import sgd
+    from repro.optim.zero1 import (
+        own_block, own_row, shard_leaf, zero1_init, zero1_view)
+
+    x = jnp.arange(np.prod(shape), dtype=jnp.float32).reshape(shape) + 1.0
+    flat = np.asarray(shard_leaf(x, n_dp))
+    for j in range(n_dp):
+        block = jax.jit(own_block, static_argnums=1)(x, n_dp, jnp.int32(j))
+        assert block.shape == (np.prod(shape[:-1]) // n_dp, shape[-1])
+        np.testing.assert_array_equal(np.asarray(block).reshape(-1), flat[j])
+        np.testing.assert_array_equal(
+            np.asarray(block).reshape(-1),
+            jax.jit(own_row, static_argnums=1)(x, n_dp, jnp.int32(j)))
+    if zero1_view(shape, n_dp, 1) == "native":  # not (8, 4) over 2
+        masters = zero1_init(sgd(momentum=0.9), {"w": x}, n_dp, 1)["master"]
+        np.testing.assert_array_equal(
+            np.asarray(masters["w"]).reshape(n_dp, -1), flat)
+
+
+def test_zero1_view_by_shape():
+    """A 1-D leaf, rows n_dp does not divide, blocks that are not whole
+    (8, 128) tiles and tp > 1 take the flat layout; every matrix of
+    granite-8b, stacked or not, the native one at one member and at four,
+    and every leaf of DeepSeek-V2-Lite with two axes or more at one."""
+    from repro.optim.zero1 import zero1_view
+
+    assert zero1_view((4096,), 1, 1) == "flat"
+    assert zero1_view((3, 128), 2, 1) == "flat"  # rows % n_dp
+    assert zero1_view((24, 128), 2, 1) == "flat"  # blocks of 12 rows
+    assert zero1_view((16, 64), 2, 1) == "flat"  # 64 of 128 lanes
+    assert zero1_view((16, 192), 2, 1) == "flat"
+    assert zero1_view((3, 4), 1, 1) == "native"  # one member: the leaf
+    assert zero1_view((16, 128), 2, 1) == "native"
+    assert zero1_view((2, 8, 256), 2, 1) == "native"
+    assert zero1_view((4096, 14336), 1, 2) == "flat"  # tp > 1
+    assert zero1_view((1, 4096), 4, 1) == "flat"  # a stacked norm scale
+    assert zero1_view((1, 4096), 1, 1) == "native"
+    granite = [(49152, 4096), (1, 4096, 1024), (1, 4096, 4096),
+               (1, 14336, 4096), (1, 4096, 14336), (4096, 49152)]
+    deepseek = [(5, 8, 2048, 1408), (5, 8, 1408, 2048), (5, 2048, 64),
+                (1, 512, 2048), (5, 2816, 2048), (12800, 2048), (5, 2048)]
+    for shape in granite:
+        for n_dp in (1, 4):
+            assert zero1_view(shape, n_dp, 1) == "native", (shape, n_dp)
+    for shape in deepseek:
+        assert zero1_view(shape, 1, 1) == "native", shape
+
+
+def _dyadic(shape, seed, scale):
+    """Small multiples of `scale` (a power of two): every sum and product
+    of the update below is exact, so no contraction of a multiply-add in a
+    fusion can round the two layouts apart."""
+    r = np.random.default_rng(seed).integers(-64, 65, size=shape)
+    return jnp.asarray(r * scale, jnp.float32)
+
+
+@pytest.mark.parametrize("opt", ["sgd", "adamw"])
+@pytest.mark.parametrize("n_dp", [1, 2])
+def test_zero1_native_update_matches_flat_bit_for_bit(opt, n_dp):
+    """Two zero1_update steps of every dp member (vmapped over the dp axis)
+    give the same parameters, and the same master and optimizer state
+    element for element, on the native layout as on the flat one (the
+    layout tp > 1 takes)."""
+    from repro.optim.zero1 import own_share, zero1_init, zero1_update
+
+    base = (sgd(momentum=0.5, weight_decay=0.25) if opt == "sgd" else
+            adamw(b1=0.5, b2=0.75, eps=2.0**-10, weight_decay=0.25))
+    shapes = {"w": (2, 16, 128), "m": (32, 128), "b": (16,)}
+    params = {k: _dyadic(s, i, 2.0**-3) for i, (k, s) in enumerate(
+        sorted(shapes.items()))}
+    grads = [{k: _dyadic(s, 10 * t + i, 2.0**-4) for i, (k, s) in enumerate(
+        sorted(shapes.items()))} for t in (1, 2)]
+
+    def run(tp):
+        def member(j):
+            own = jax.tree.map(lambda p: own_share(p, n_dp, j, tp), params)
+            restack = lambda t: jax.tree.map(
+                lambda x: x[None] if x.ndim >= 1 else x, t)
+            state = {"master": restack(own), "base": restack(base.init(own))}
+            p = params
+            for g in grads:
+                p, state = zero1_update(
+                    base, state, g, jnp.float32(0.25), dp_axes=("dp",),
+                    dp_index=j, n_dp=n_dp, tp=tp, param_dtype=jnp.float32,
+                    params_like=p)
+            return own, p, state
+
+        return jax.jit(jax.vmap(member, axis_name="dp"))(jnp.arange(n_dp))
+
+    (own_n, p_n, s_n), (own_f, p_f, s_f) = run(1), run(2)
+    assert s_n["master"]["w"].shape == (n_dp, 1, 32 // n_dp, 128)
+    assert s_f["master"]["w"].shape == (n_dp, 1, 4096 // n_dp)
+    # each member's share is its block (row) of the global layout
+    for own, tp in ((own_n, 1), (own_f, 2)):
+        masters = zero1_init(base, params, n_dp, tp)["master"]
+        for a, b in zip(jax.tree.leaves(own), jax.tree.leaves(masters)):
+            np.testing.assert_array_equal(a, b)
+    for a, b in zip(jax.tree.leaves(p_n), jax.tree.leaves(p_f)):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(jax.tree.leaves(s_n), jax.tree.leaves(s_f)):
+        np.testing.assert_array_equal(
+            np.asarray(a).reshape(n_dp, -1), np.asarray(b).reshape(n_dp, -1))
+
+
+@pytest.mark.parametrize("n_dp,flat", [(1, 1), (4, 3)])
+def test_zero1_view_line_at_granite_widths(n_dp, flat):
+    """granite-8b at its published widths and 1 layer, as the chip
+    benchmark runs it: every leaf but the final norm scale is held as
+    blocks of its own rows on one chip; over four, the layer's two norm
+    scales (one row each) stay flat too."""
+    import json
+    import pathlib
+
+    from repro.configs.base import ModelConfig
+    from repro.launch.train import zero1_view_line
+
+    path = (pathlib.Path(__file__).parents[1]
+            / "benchmarks/chip/configs/granite-8b.json")
+    c = json.loads(path.read_text())
+    cfg = ModelConfig(**{k: c[k] for k in (
+        "name", "family", "n_layers", "d_model", "n_heads", "n_kv_heads",
+        "d_ff", "vocab", "head_dim", "rope_theta", "tie_embeddings")})
+    params = jax.eval_shape(
+        lambda k: init_lm_params(k, cfg, tp=1, n_shards=1,
+                                 dtype=jnp.float32), jax.random.PRNGKey(0))
+    shapes = [x.shape for x in jax.tree.leaves(params)]
+    assert len(shapes) == 12
+    total = 620769280
+    assert zero1_view_line(shapes, n_dp=n_dp, tp=1) == (
+        f"[train] zero1 view: {total - flat * 4096} of {total} in place, "
+        f"{flat} leaves flat")
